@@ -42,6 +42,9 @@ def write_json(path: str, tag: str) -> dict:
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
         "jax_version": jax.__version__,
         "jax_backend": jax.default_backend(),
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
         "schemes": RESULTS,
         "rows": [{"name": n, "us_per_call": us, "derived": d}
                  for n, us, d in ROWS],

@@ -22,6 +22,7 @@ from . import (bench_cc, bench_direction, bench_layout, bench_multisource,
                bench_slimsell, bench_slimwork, bench_sssp, bench_storage,
                bench_vs_traditional, bench_work, bench_workloads)
 from . import common
+from repro.compile_cache import use_compile_cache
 
 ALL = {
     "storage": bench_storage,            # Table III / Fig 7
@@ -70,6 +71,7 @@ def main(argv=None) -> int:
     ap.add_argument("--check-teps", action="store_true",
                     help="exit nonzero when any recorded TEPS is NaN/zero")
     args = ap.parse_args(argv)
+    use_compile_cache()
     names = [n for n in args.only.split(",") if n] or list(ALL)
     print("name,us_per_call,derived")
     for name in names:

@@ -16,7 +16,7 @@ import time
 import jax
 import numpy as np
 
-from repro.compat import make_mesh
+from repro.compile_cache import use_compile_cache
 from repro.core.bfs import bfs
 from repro.core.bfs_traditional import bfs_traditional
 from repro.core.formats import build_slimsell
@@ -34,6 +34,7 @@ def main():
     ap.add_argument("--backend", default="jnp", choices=["jnp", "pallas"])
     ap.add_argument("--no-validate", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     t0 = time.time()
     csr = kronecker(args.scale, args.ef, seed=1)
@@ -47,8 +48,12 @@ def main():
                        validate=not args.no_validate, csr=csr, tiled=tiled)
     print(rep.summary())
 
-    if len(jax.devices()) >= 4:
-        from repro.core.dist_bfs import make_dist_bfs, partition_slimsell
+    if len(jax.devices()) < 4:
+        print(f"distributed 2D BFS skipped: {len(jax.devices())} "
+              f"{jax.devices()[0].platform} device(s), needs 4")
+    else:
+        from repro.core.dist_bfs import (make_dist_bfs, make_mesh,
+                                         partition_slimsell)
         mesh = make_mesh((2, 2), ("data", "model"))
         dist = partition_slimsell(csr, R=2, Co=2)
         fn = make_dist_bfs(mesh, dist, args.semiring, backend=args.backend)

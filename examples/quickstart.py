@@ -138,7 +138,7 @@ def main():
     #    come from the shared engine's distributed strategy.
     import jax
     import jax.numpy as jnp
-    from repro.compat import make_mesh
+    from repro.core.dist_bfs import make_mesh
     if jax.local_device_count() < 4:
         # the XLA flag only grows the *cpu* platform; on a 1-GPU/TPU default
         # backend there is no 2x2 mesh to build — skip the demo, don't crash

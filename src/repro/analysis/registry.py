@@ -131,4 +131,5 @@ def demo_layout() -> Dict[str, Any]:
     scenarios.append(("slimwork", ids, n_active))
     return dict(T=T, C=C, L=L, chunk_blk=chunk_blk, n_chunks=n_chunks,
                 n_blk=n_blk, n_pad=n_pad, row_block=row_block,
+                x_rows=(8, 128),  # n_pad laid out as kernels.sweep.as_rows
                 scenarios=scenarios)

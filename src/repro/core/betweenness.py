@@ -201,8 +201,7 @@ def betweenness(tiled, sources: Optional[Sequence[int]] = None, *,
     iters = 0
     run = eng.run_fused if cfg.mode == "fused" else eng.run_hostloop
     with cfg.applied():
-        for start, batch, batch_p in _iter_batches(roots, batch_size,
-                                                   cfg.backend):
+        for start, batch, batch_p in _iter_batches(roots, batch_size):
             roots_p = jnp.asarray(batch_p, jnp.int32)
             fwd = run(BRANDES_FORWARD_SPEC, tiled, roots_p,
                       slimwork=slimwork, max_iters=cap, backend=cfg.backend)

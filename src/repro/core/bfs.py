@@ -267,6 +267,20 @@ def dp_transform(tiled, d: Array, root) -> Array:
     return p.at[root].set(root)
 
 
+def columnwise(fn, tiled, D: Array, roots) -> Array:
+    """``fn(tiled, D[:, b], roots[b])`` for every column b of ``D`` [n, B],
+    stacked [B, n] — the parent transforms (``dp_transform``,
+    ``sssp.sssp_parents``) of a batch.
+
+    The columns run one after another on the device: a vmapped transform
+    would gather [T, C, L, B] at once, B times the cols array (19 GB for one
+    Graph500 scale-18 batch of 8 on a TPU, whose layout pads that minor
+    axis to 128 lanes).
+    """
+    return jax.lax.map(lambda col: fn(tiled, col[0], col[1]),
+                       (jnp.asarray(D).T, jnp.asarray(roots)))
+
+
 # ----------------------------------------------------------------- public API
 
 

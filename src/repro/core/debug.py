@@ -19,9 +19,9 @@ Covered strategies: fused (the whole ``lax.while_loop`` is checkified, so
 per-iteration sweep checks accumulate through the loop carry), hostloop
 (each jitted step is checkified; the layout is additionally validated
 eagerly on host), and distributed (the ``make_dist_*`` runners route
-through a checkified twin of the shard-mapped fixpoint — the repo's
-``shard_map`` shim already passes ``check_rep=False``, which checkify
-requires).
+through a checkified twin of the shard-mapped fixpoint — the runners
+pass ``check_vma=False``, which checkify requires). Sanitized Pallas runs
+keep the user checks but not checkify's index checks (``_errors_for``).
 
 Mechanics: entering ``checked()`` sets a thread-local error set; the engine
 routes execution to a cached ``jax.jit(checkify.checkify(impl))`` twin of
@@ -232,10 +232,23 @@ def checkified(fn, *, static_argnames=(), errs: Optional[frozenset] = None):
     return cf
 
 
-def call_checked(fn, *args, static_argnames=(), **kwargs):
+def _errors_for(pallas: bool) -> frozenset:
+    """The active error set, less checkify's index checks when the traced
+    function runs Pallas kernels: JAX 0.9's Pallas checkify rule traces its
+    grid-bounds check with float32 zero blocks and fails on every int32
+    block operand (each SlimSell kernel's ``cols``). The user checks stay —
+    ``check_layout`` bounds every column id the kernels read."""
+    errs = errors() or default_errors()
+    return errs - checkify.index_checks if pallas else errs
+
+
+def call_checked(fn, *args, static_argnames=(), pallas: bool = False,
+                 **kwargs):
     """Run ``fn`` through its checkified twin, emitting the engine's
-    invariant checks during the trace, and throw on any error."""
-    cf = checkified(fn, static_argnames=static_argnames)
+    invariant checks during the trace, and throw on any error. ``pallas``
+    marks a function that runs Pallas kernels (see ``_errors_for``)."""
+    cf = checkified(fn, static_argnames=static_argnames,
+                    errs=_errors_for(pallas))
     # the checkify wrapper erases fn's signature, so positional statics
     # would not match static_argnames — bind everything to keywords
     bound = inspect.signature(fn).bind(*args, **kwargs)
@@ -249,18 +262,19 @@ def call_checked(fn, *args, static_argnames=(), **kwargs):
     return out
 
 
-def jit_checked(fn):
+def jit_checked(fn, *, pallas: bool = False):
     """Drop-in replacement for ``jax.jit(fn)`` (no static args) that routes
     each call through a checkified twin while the sanitizer is active — the
     distributed factories return this so ``make_dist_*`` runners pick up
-    ``checked()`` at call time, not factory time."""
+    ``checked()`` at call time, not factory time. ``pallas`` as in
+    ``call_checked``."""
     jitted = jax.jit(fn)
 
     @functools.wraps(fn)
     def call(*args, **kwargs):
         if not enabled():
             return jitted(*args, **kwargs)
-        cf = checkified(fn)
+        cf = checkified(fn, errs=_errors_for(pallas))
         prev = _get("emit", False)
         _STATE.emit = True
         try:

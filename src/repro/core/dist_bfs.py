@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from . import debug
 from . import engine as eng
 from .betweenness import BRANDES_BACKWARD_SPEC, BRANDES_FORWARD_SPEC
@@ -106,6 +105,15 @@ def _tiled_unflatten(aux, ch):
 
 
 jax.tree_util.register_pytree_node(DistSlimSell, _tiled_flatten, _tiled_unflatten)
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """A device mesh for the ``make_dist_*`` factories: ``jax.make_mesh``
+    with Auto axis types (the shard-mapped sweeps place their operands with
+    explicit ``PartitionSpec``s, not sharding-in-types)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def partition_slimsell(csr: CSRGraph, R: int, Co: int, *, C: int = 8,
@@ -298,7 +306,7 @@ def make_dist_bfs_sliced(mesh: Mesh, meta: DistSlimSell, *,
         if pod_axis else P(row_axis, col_axis, None, None, None)
     rb_spec = P(*(lead + (row_axis, col_axis, None))) \
         if pod_axis else P(row_axis, col_axis, None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda c, rb, r: bfs_shard(c, rb, r), mesh=mesh,
         in_specs=(cols_spec, rb_spec, P()),
         out_specs=(P(row_axis, None), P()),
@@ -408,11 +416,11 @@ def make_dist_fixpoint(mesh: Mesh, meta: DistSlimSell, spec: FixpointSpec, *,
         in_specs.append(block_spec)           # wts, in lockstep with cols
     in_specs.append(P())                      # arg
     in_specs.append(P())                      # ctx_args tuple (P() is a prefix)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=P(),
         check_vma=False,
     )
-    return debug.jit_checked(sharded)
+    return debug.jit_checked(sharded, pallas=backend == "pallas")
 
 
 # ---------------------------------------------------- per-algorithm factories
@@ -523,8 +531,8 @@ def make_dist_multi_sssp(mesh: Mesh, meta: DistSlimSell, *,
     the per-column phase machines run replicated (same flattened
     delta-stepping state as ``multi_source_sssp``), so per-root sweeps and
     buckets match the single-device engine exactly. The local sweep feeds
-    the raw batch width to the SpMM kernel (gcd lane-tile fallback for
-    widths that 128 does not divide)."""
+    the raw batch width to the SpMM kernel, which pads widths over 128 to
+    whole lane tiles."""
     run = make_dist_fixpoint(
         mesh, meta, MULTI_SSSP_SPEC, row_axes=row_axes, col_axes=col_axes,
         max_iters=max_iters, comm=comm, backend=backend, direction="push",

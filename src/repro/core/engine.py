@@ -306,7 +306,8 @@ def run_fused(spec: FixpointSpec, tiled, arg, *, ctx_args=(),
     check_choice("direction", direction, DIRECTIONS)
     check_choice("backend", backend, BACKENDS)
     runner = partial(debug.call_checked, _run_fused_impl,
-                     static_argnames=_FUSED_STATICS) \
+                     static_argnames=_FUSED_STATICS,
+                     pallas=backend == "pallas") \
         if debug.enabled() else _run_fused
     state, iters, work, dirs, plog = runner(
         spec, tiled, arg, tuple(ctx_args), slimwork=slimwork,
@@ -374,7 +375,8 @@ class FixpointHandle:
     def run(self, tiled, ctx, state):
         """Drive ``state`` to the fixpoint; async ``(state, iterations)``."""
         if debug.enabled():
-            return debug.call_checked(self._impl, tiled, ctx, state)
+            return debug.call_checked(self._impl, tiled, ctx, state,
+                                      pallas=self.backend == "pallas")
         return self._jitted(tiled, ctx, state)
 
 
@@ -617,12 +619,13 @@ def run_hostloop(spec: FixpointSpec, tiled, arg, *, ctx_args=(),
         # eager twin of check_layout, then checkified per-step twins so the
         # in-sweep checks ride inside each jitted step
         debug.validate_layout_host(tiled)
+        pallas = backend == "pallas"
         zero_step = partial(debug.call_checked, _zero_step_impl,
-                            static_argnames=_ZEROSTEP_STATICS)
+                            static_argnames=_ZEROSTEP_STATICS, pallas=pallas)
         subset_step = partial(debug.call_checked, _subset_step_impl,
-                              static_argnames=_SUBSET_STATICS)
+                              static_argnames=_SUBSET_STATICS, pallas=pallas)
         full_step = partial(debug.call_checked, _full_step_impl,
-                            static_argnames=_FULLSTEP_STATICS)
+                            static_argnames=_FULLSTEP_STATICS, pallas=pallas)
     else:
         zero_step, subset_step, full_step = _zero_step, _subset_step, _full_step
     width = int(np.asarray(arg).shape[0]) if spec.batched else None
@@ -732,11 +735,9 @@ def dist_step(spec: FixpointSpec, ctx, local, state, k, dnow, *,
     def push_fn(state):
         # per-shard SlimWork push mask: the partition's own (localized
         # column, tile) incidence pairs select the tiles holding >=1
-        # frontier column of THIS shard's column range. jnp-only on the
-        # mesh, for the same interpret-mode pallas scalar-prefetch reason
-        # as the pull mask below
+        # frontier column of THIS shard's column range
         mask = None
-        if backend == "jnp" and local.inc_src is not None:
+        if local.inc_src is not None:
             sb = spec.source_bits(ctx, state, k)
             sb_pad = ((0, Co * n_col - n),) + ((0, 0),) * (sb.ndim - 1)
             sb_local = jax.lax.dynamic_slice_in_dim(
@@ -748,13 +749,7 @@ def dist_step(spec: FixpointSpec, ctx, local, state, k, dnow, *,
     def pull_fn(state):
         nf = spec.not_final(ctx, state)
         nf_rows = nf.any(axis=-1) if nf.ndim > 1 else nf
-        # SlimWork tile compaction turns the mask into per-device
-        # scalar-prefetch operands (tile_ids / n_active); under shard_map
-        # the jax-0.4.37 interpret-mode pallas grid mishandles
-        # device-varying values of those (observed: one shard's empty mask
-        # silencing every shard's sweep), so the tile mask is jnp-only on
-        # the mesh — the pallas path still early-exits per row via ``nf``
-        mask = _pull_tile_mask(local, nf_rows) if backend == "jnp" else None
+        mask = _pull_tile_mask(local, nf_rows)
         return _sweep(spec, local, x_local, w, mask, nf, backend, pull=True)
 
     if direction == "push":

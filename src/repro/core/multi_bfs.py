@@ -47,8 +47,8 @@ from . import engine as eng
 from . import packing
 from . import semiring as sm
 from .bfs import (_check_bfs_options, _check_packed, _frontier_payload,
-                  _host_direction_bits, _ids1, _not_final, dp_transform,
-                  semiring_update)
+                  _host_direction_bits, _ids1, _not_final, columnwise,
+                  dp_transform, semiring_update)
 from .engine import DIRECTIONS, WORK_LOG, FixpointSpec  # noqa: F401
 from .options import EngineConfig, resolve_config
 
@@ -100,22 +100,17 @@ def _init_state_multi(sr_name: str, n: int, roots: Array):
     raise ValueError(sr_name)
 
 
-def _iter_batches(roots: np.ndarray, batch_size: Optional[int], backend: str):
+def _iter_batches(roots: np.ndarray, batch_size: Optional[int]):
     """Resolve the device batch width and yield ``(start, batch, padded)``
     slices — the batching scaffold shared by the multi-source BFS and SSSP
     front doors.
 
-    The width defaults to all roots in one batch. On the pallas backend the
-    SpMM kernels tile the batch axis in lanes of 128, so widths over one
-    lane tile must divide evenly: round up and let column padding (repeat
-    the last root) absorb the slack — callers drop the padded columns. The
-    final partial batch is padded the same way.
+    The width defaults to all roots in one batch. The final partial batch
+    is padded by repeating its last root — callers drop the padded columns.
     """
     B = int(batch_size) if batch_size is not None else roots.size
     if B <= 0:
         raise ValueError(f"batch_size must be positive, got {batch_size}")
-    if backend == "pallas" and B > 128 and B % 128:
-        B = -(-B // 128) * 128
     for start in range(0, roots.size, B):
         batch = roots[start:start + B]
         pad = B - batch.size
@@ -255,8 +250,7 @@ def multi_source_bfs(tiled, roots: Sequence[int],
     d_out = np.empty((roots.size, n), np.int32)
     p_out = np.empty((roots.size, n), np.int32) if need_parents else None
     iters, work_rows, plog_rows = [], [], []
-    for start, batch, batch_p in _iter_batches(roots, batch_size,
-                                               cfg.backend):
+    for start, batch, batch_p in _iter_batches(roots, batch_size):
         # the packed spec's plane geometry is static per batch width
         spec = packed_multi_bfs_spec(batch_p.size) if packed \
             else multi_bfs_spec(semiring)
@@ -278,10 +272,8 @@ def multi_source_bfs(tiled, roots: Sequence[int],
             if semiring == "selmax":
                 p = np.asarray(state["p"].astype(jnp.int32) - 1).T
             else:
-                p = np.asarray(jax.vmap(
-                    dp_transform, in_axes=(None, 1, 0))(
-                        tiled, jnp.asarray(state["d"]),
-                        jnp.asarray(batch_p)))
+                p = np.asarray(columnwise(dp_transform, tiled, state["d"],
+                                          batch_p))
             p_out[start:start + batch.size] = p[: batch.size]
             for b, r in enumerate(batch):
                 p_out[start + b, int(r)] = int(r)

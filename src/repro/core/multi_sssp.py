@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import engine as eng
+from .bfs import columnwise
 from .engine import FixpointSpec
 from .multi_bfs import _iter_batches
 from .options import EngineConfig, MODES, check_choice, resolve_config
@@ -235,8 +236,8 @@ def multi_source_sssp(tiled, roots: Sequence[int], *,
     config: the engine knobs as one ``EngineConfig`` — mode "fused" (one
     flattened lax.while_loop on device) or "hostloop" (host loop + union
     SlimWork tile gathering per sweep); backend "jnp" (reference) or
-    "pallas" (stored-weight SlimSell SpMM kernel; batch widths not
-    divisible by the 128-lane tile fall back to gcd lane tiles).
+    "pallas" (stored-weight SlimSell SpMM kernel; batch widths over 128
+    are padded to whole 128-lane tiles).
     Delta-stepping is push-only, so the config's direction must stay the
     default "push". The per-call ``mode``/``backend`` kwargs are the
     deprecated spelling.
@@ -272,8 +273,7 @@ def multi_source_sssp(tiled, roots: Sequence[int], *,
     sweeps = np.empty(roots.size, np.int32)
     buckets = np.empty(roots.size, np.int32)
     iters, work_rows = [], []
-    for start, batch, batch_p in _iter_batches(roots, batch_size,
-                                               cfg.backend):
+    for start, batch, batch_p in _iter_batches(roots, batch_size):
         with cfg.applied():
             if cfg.mode == "fused":
                 res = eng.run_fused(MULTI_SSSP_SPEC, tiled,
@@ -296,8 +296,8 @@ def multi_source_sssp(tiled, roots: Sequence[int], *,
         buckets[start:start + batch.size] = \
             np.asarray(state["buckets"])[: batch.size]
         if need_parents:
-            p = np.asarray(jax.vmap(sssp_parents, in_axes=(None, 1, 0))(
-                tiled, jnp.asarray(state["dist"]), jnp.asarray(batch_p)))
+            p = np.asarray(columnwise(sssp_parents, tiled, state["dist"],
+                                      batch_p))
             p_out[start:start + batch.size] = p[: batch.size]
         iters.append(res.iterations)
         if log_work:

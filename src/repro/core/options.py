@@ -20,7 +20,7 @@ values validated against these constants.
 It also owns the Pallas ``interpret`` default (``default_interpret``):
 interpret mode on every non-TPU backend so the kernels are validated in CI,
 compiled on real TPUs, overridable through the ``REPRO_PALLAS_INTERPRET``
-environment variable for the ROADMAP ``interpret=False`` calibration runs.
+environment variable. Forcing interpret mode on a TPU raises.
 
 Since the serving PR this module also owns the **one** engine-knob record,
 ``EngineConfig``: a frozen dataclass bundling (backend, direction, mode,
@@ -100,15 +100,15 @@ def default_interpret() -> bool:
 
     Resolution order: an active ``interpret_override`` context (how
     ``EngineConfig.interpret`` lands), then ``REPRO_PALLAS_INTERPRET=1|0``;
-    unset/"auto" interprets everywhere except on a real TPU backend —
-    identical to the old per-wrapper behavior on CPU CI.
+    unset/"auto" interprets everywhere except on a real TPU backend.
+    Interpret mode forced on a TPU raises (``resolve_interpret``).
     """
     override = getattr(_INTERPRET_STATE, "value", None)
     if override is not None:
-        return bool(override)
+        return resolve_interpret(bool(override))
     v = os.environ.get(INTERPRET_ENV, "auto").strip().lower()
     if v in ("1", "true", "yes"):
-        return True
+        return resolve_interpret(True)
     if v in ("0", "false", "no"):
         return False
     if v not in ("", "auto"):
@@ -119,8 +119,21 @@ def default_interpret() -> bool:
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Map None -> the env-overridable repo default; pass explicit bools."""
-    return default_interpret() if interpret is None else bool(interpret)
+    """Map None -> the env-overridable repo default; pass explicit bools.
+
+    Interpret mode on a TPU backend is an error, not a quiet slow path: a
+    run on the chip either compiles its kernels or fails.
+    """
+    if interpret is None:
+        return default_interpret()
+    if interpret:
+        import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"Pallas interpret mode requested on a TPU backend (via "
+                f"{INTERPRET_ENV}, EngineConfig(interpret=True) or "
+                f"interpret=True); the kernels run compiled on a TPU")
+    return bool(interpret)
 
 
 def check_choice(name: str, value, allowed: Sequence[str], *,

@@ -16,7 +16,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.registry import KernelCase, kernel_contract
-from repro.compat import tpu_memory_space
 from repro.core.options import resolve_interpret
 
 
@@ -44,7 +43,7 @@ def _bag_kernel(bags_ref, table_ref, out_ref, scratch_ref, sem, *,
                              jnp.zeros((), jnp.float32)))
         if mode == "mean":
             acc = acc / jnp.maximum(cnt, 1.0)
-        pl.store(out_ref, (pl.ds(b, 1), slice(None)), acc[None])
+        out_ref[pl.ds(b, 1), :] = acc[None]
         return 0
 
     jax.lax.fori_loop(0, b_blk, one_bag, 0)
@@ -60,7 +59,7 @@ def bag_grid_spec(B, K, d, b_blk, d_tile, dtype):
         in_specs=[
             pl.BlockSpec((b_blk, K), lambda b, dt: (b, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=tpu_memory_space().ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((b_blk, d_tile), lambda b, dt: (b, dt)),
         scratch_shapes=[

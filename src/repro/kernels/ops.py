@@ -1,9 +1,9 @@
 """Jitted wrappers around the Pallas kernels.
 
 The wrappers own everything outside the hot loop: SlimWork tile-id
-compaction, chunk-row -> vertex-space scatter, padding, and the
+compaction, chunk-row -> vertex-space scatter, GCN slot weights, and the
 interpret-mode switch (True on CPU so the kernels are validated everywhere;
-False on a real TPU backend).
+compiled on a TPU, where interpret mode is an error).
 """
 from __future__ import annotations
 
@@ -15,19 +15,12 @@ import jax.numpy as jnp
 from repro.core import packing
 from repro.core import semiring as sm
 from repro.core.options import check_choice, resolve_interpret
-from .slimsell_spmv import slimsell_spmv_pallas, semiring_ops
+from .slimsell_spmv import slimsell_spmv_pallas
 from .slimsell_spmm import slimsell_spmm_pallas
 from .slimsell_packed import (slimsell_spmm_packed_pallas,
                               slimsell_spmv_packed_pallas)
 from .slimsell_pull import slimsell_pull_mm_pallas, slimsell_pull_pallas
 from .embedding_bag import embedding_bag_pallas
-
-
-def _default_interpret() -> bool:
-    # kept as a name for callers; the policy (env override + backend
-    # detection) lives in core.options
-    from repro.core.options import default_interpret
-    return default_interpret()
 
 
 def compact_tile_ids(tile_mask):
@@ -44,24 +37,21 @@ def compact_tile_ids(tile_mask):
     return ids, n_active.reshape(1)
 
 
-def _scatter_blocks(sr, tiled, y_blocks, tile_mask):
-    """Shared kernel epilogue: zero never-visited chunk blocks, scatter to
-    vertex space.
+def _grid_order(tiled, tile_mask):
+    """Grid order of the tiles and its live-step count: every tile, or the
+    SlimWork compaction of ``tile_mask``."""
+    if tile_mask is None:
+        T = tiled.cols.shape[0]
+        return jnp.arange(T, dtype=jnp.int32), jnp.asarray([T], jnp.int32)
+    return compact_tile_ids(tile_mask)
 
-    Chunk blocks the grid never visited hold garbage; a chunk is visited iff
-    some tile maps to it (always true for the full tile set, not for hostloop
-    subsets) AND, under SlimWork, some such tile is active. ``y_blocks`` is
-    [n_chunks, C] (spmv/pull) or [n_chunks, C, d] (spmm).
+
+def _scatter_blocks(sr, tiled, y_blocks):
+    """Shared kernel epilogue: scatter chunk-row blocks to vertex space.
+
+    ``y_blocks`` is [n_chunks, C] (spmv/pull) or [n_chunks, C, d] (spmm);
+    chunk blocks no active tile visited already hold the semiring zero.
     """
-    covered = jax.ops.segment_max(jnp.ones_like(tiled.row_block),
-                                  tiled.row_block,
-                                  num_segments=tiled.n_chunks) > 0
-    if tile_mask is not None:
-        covered &= jax.ops.segment_max(tile_mask.astype(jnp.int32),
-                                       tiled.row_block,
-                                       num_segments=tiled.n_chunks) > 0
-    cov = covered.reshape((-1,) + (1,) * (y_blocks.ndim - 1))
-    y_blocks = jnp.where(cov, y_blocks, jnp.asarray(sr.zero, y_blocks.dtype))
     rv = tiled.row_vertex.reshape(-1)
     ids = jnp.where(rv < 0, tiled.n, rv)
     flat = y_blocks.reshape(-1) if y_blocks.ndim == 2 \
@@ -80,18 +70,13 @@ def spmv(sr_name: str, tiled, x, tile_mask=None, weights=None, interpret=None):
     """
     interpret = resolve_interpret(interpret)
     sr = sm.get(sr_name)
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     x = x.astype(sr.dtype)
     y_blocks = slimsell_spmv_pallas(
         tiled.cols, tile_ids, tiled.row_block, n_active, x,
         sr_name=sr_name, n_chunks=tiled.n_chunks, interpret=interpret,
         wts=weights)
-    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks], tile_mask)
+    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -105,18 +90,13 @@ def spmv_packed(tiled, x_words, tile_mask=None, interpret=None):
     vertex appears exactly once, and ``pack_bits`` re-packs.
     """
     interpret = resolve_interpret(interpret)
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     y_blocks = slimsell_spmv_packed_pallas(
         tiled.cols, tile_ids, tiled.row_block, n_active,
         x_words.astype(jnp.uint32),
         n_chunks=tiled.n_chunks, interpret=interpret)
     bits = _scatter_blocks(sm.get("boolean"), tiled,
-                           y_blocks[: tiled.n_chunks], tile_mask)
+                           y_blocks[: tiled.n_chunks])
     return packing.pack_bits(bits > 0)
 
 
@@ -130,17 +110,12 @@ def spmm_packed(tiled, X_words, tile_mask=None, interpret=None):
     """
     interpret = resolve_interpret(interpret)
     sr = sm.get("boolean_packed")
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     y_blocks = slimsell_spmm_packed_pallas(
         tiled.cols, tile_ids, tiled.row_block, n_active,
         X_words.astype(jnp.uint32),
         n_chunks=tiled.n_chunks, interpret=interpret)
-    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks], tile_mask)
+    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks])
 
 
 @functools.partial(jax.jit, static_argnames=("sr_name", "interpret"))
@@ -153,12 +128,7 @@ def pull(sr_name: str, tiled, x, row_mask, tile_mask=None, interpret=None):
     """
     interpret = resolve_interpret(interpret)
     sr = sm.get(sr_name)
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     x = x.astype(sr.dtype)
     # not-final bits in chunk-row space (padding rows are never pending)
     rv = tiled.row_vertex                                  # [n_chunks, C]
@@ -167,7 +137,7 @@ def pull(sr_name: str, tiled, x, row_mask, tile_mask=None, interpret=None):
     y_blocks = slimsell_pull_pallas(
         tiled.cols, tile_ids, tiled.row_block, n_active, nf, x,
         sr_name=sr_name, n_chunks=tiled.n_chunks, interpret=interpret)
-    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks], tile_mask)
+    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks])
 
 
 @functools.partial(jax.jit, static_argnames=("sr_name", "interpret"))
@@ -180,12 +150,7 @@ def pull_mm(sr_name: str, tiled, X, row_mask, tile_mask=None, interpret=None):
     """
     interpret = resolve_interpret(interpret)
     sr = sm.get(sr_name)
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     X = X.astype(sr.dtype)
     # per-column not-final bits in chunk-row space; padding rows never pend
     rv = tiled.row_vertex                                  # [n_chunks, C]
@@ -195,7 +160,7 @@ def pull_mm(sr_name: str, tiled, X, row_mask, tile_mask=None, interpret=None):
     y_blocks = slimsell_pull_mm_pallas(
         tiled.cols, tile_ids, tiled.row_block, n_active, nf, X,
         sr_name=sr_name, n_chunks=tiled.n_chunks, interpret=interpret)
-    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks], tile_mask)
+    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks])
 
 
 @functools.partial(jax.jit, static_argnames=("sr_name", "weighted", "interpret"))
@@ -206,24 +171,33 @@ def spmm(sr_name: str, tiled, X, deg=None, weighted=False, tile_mask=None,
     weights: optional stored per-slot weights float32[T, C, L] (SlimSell-W);
     routes to the stored-weight kernel, whose weight block shares the cols
     block's tile indirection — the batched min-plus (multi-source SSSP)
-    operand. Mutually exclusive with the derived GCN ``weighted=`` path.
+    operand. ``weighted=True`` is GCN's sym-norm aggregation: the slot
+    weight rsqrt(deg[row]) * rsqrt(deg[col]) is derived from ``deg`` here
+    and swept as the stored weight. The two are mutually exclusive.
     """
     interpret = resolve_interpret(interpret)
     sr = sm.get(sr_name)
-    T = tiled.cols.shape[0]
-    if tile_mask is None:
-        tile_ids = jnp.arange(T, dtype=jnp.int32)
-        n_active = jnp.asarray([T], jnp.int32)
-    else:
-        tile_ids, n_active = compact_tile_ids(tile_mask)
-    rv_tiles = jnp.take(tiled.row_vertex, tiled.row_block, axis=0)  # [T, C]
+    if weighted:
+        if weights is not None:
+            raise ValueError("pass stored weights= or the derived GCN "
+                             "weighted= path, not both")
+        weights = _gcn_slot_weights(tiled, deg)
+    tile_ids, n_active = _grid_order(tiled, tile_mask)
     y_blocks = slimsell_spmm_pallas(
-        tiled.cols, tile_ids, tiled.row_block, n_active, rv_tiles,
-        X.astype(sr.dtype) if not weighted else X,
-        deg if deg is not None else jnp.ones((tiled.n,), jnp.float32),
-        sr_name=sr_name, n_chunks=tiled.n_chunks, weighted=weighted,
-        interpret=interpret, wts=weights)
-    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks], tile_mask)
+        tiled.cols, tile_ids, tiled.row_block, n_active, X.astype(sr.dtype),
+        sr_name=sr_name, n_chunks=tiled.n_chunks, interpret=interpret,
+        wts=weights)
+    return _scatter_blocks(sr, tiled, y_blocks[: tiled.n_chunks])
+
+
+def _gcn_slot_weights(tiled, deg):
+    """GCN's sym-norm weight of every SlimSell slot (padding slots get 0)."""
+    degv = deg.astype(jnp.float32)
+    rv = jnp.take(tiled.row_vertex, tiled.row_block, axis=0)     # [T, C]
+    safe = jnp.maximum(tiled.cols, 0)
+    w = (jax.lax.rsqrt(jnp.take(degv, jnp.maximum(rv, 0)))[..., None]
+         * jax.lax.rsqrt(jnp.take(degv, safe)))
+    return jnp.where(tiled.cols < 0, 0.0, w)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))

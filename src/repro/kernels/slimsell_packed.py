@@ -8,10 +8,11 @@ revisit accumulation + SlimWork scalar-prefetch grid indirection) with the
 scalar kernels:
 
 * ``slimsell_spmv_packed_pallas`` — single-source: the frontier is a packed
-  bitmap ``uint32[ceil(n/32)]`` pinned in VMEM (32x smaller than the lane
-  frontier, DMA'd once). Each column slot gathers the *word* holding its
-  bit and extracts the bit in-register — the packed twin of the paper's
-  CMP+BLEND implicit-``val`` derivation; still nothing stored per edge.
+  bitmap ``uint32[ceil(n/32)]`` pinned in VMEM as ``[rows, 128]`` words
+  (32x smaller than the lane frontier, DMA'd once per call). Each column
+  slot loads the row of words holding its bit, selects the word's lane and
+  extracts the bit in-register — the packed twin of the paper's CMP+BLEND
+  implicit-``val`` derivation; still nothing stored per edge.
   The per-row OR over column slots lands in the usual [chunk_blk, C]
   output block; the wrapper re-packs vertex space.
 * ``slimsell_spmm_packed_pallas`` — multi-source: B roots become
@@ -28,7 +29,6 @@ grids too.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -38,69 +38,65 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.analysis.registry import KernelCase, demo_layout, kernel_contract
 from repro.core import packing
 from repro.core.options import resolve_interpret
+from . import sweep
 
 
-def _spmv_packed_kernel(tile_ids_ref, row_block_ref, n_active_ref,
-                        cols_ref, x_ref, out_ref, *, chunk_blk: int):
-    """One grid step = one SlimSell tile over the packed frontier bitmap."""
+def _spmv_packed_kernel(steps_ref, chunks_ref, live_ref, cols_ref, x_ref,
+                        acc_ref, out_ref, *, chunk_blk: int):
+    """One grid step = one SlimSell tile over the packed frontier bitmap:
+    each column slot loads the row of words holding its bit, picks the
+    word's lane and extracts the bit in-register."""
     t = pl.program_id(0)
-    tid = tile_ids_ref[t]
-    chunk = row_block_ref[tid]
-    blk = chunk // chunk_blk
+    row = sweep.open_block(t, chunks_ref, acc_ref, out_ref, chunk_blk)
+    _, C, L = cols_ref.shape
 
-    prev_tid = tile_ids_ref[jnp.maximum(t - 1, 0)]
-    prev_blk = row_block_ref[prev_tid] // chunk_blk
-    first_visit = (t == 0) | (blk != prev_blk)
-
-    @pl.when(first_visit)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(t < n_active_ref[0])
+    @pl.when(t < live_ref[0])
     def _work():
-        cols = cols_ref[0]                       # [C, L]
-        pad = cols < 0
-        safe = jnp.where(pad, 0, cols)
-        xw = x_ref[...]                          # uint32[W], VMEM-resident
-        bit = packing.gather_bits(xw, safe.reshape(-1)).reshape(cols.shape)
-        hit = jnp.where(pad, 0, bit.astype(jnp.int32))
-        red = hit.max(axis=-1)                   # [C]  OR of 0/1 bits
-        row = chunk % chunk_blk
-        cur = pl.load(out_ref, (pl.ds(row, 1), slice(None)))
-        pl.store(out_ref, (pl.ds(row, 1), slice(None)),
-                 jnp.maximum(cur, red[None, :]))
+        def slot_value(col, c, l):
+            safe = jnp.maximum(col, 0)
+            bit = packing.bit_of(safe).astype(jnp.uint32)
+            word = jnp.where(col >= 0, packing.word_of(safe), -1)
+            return sweep.lane_select(
+                x_ref, word, 0,
+                lambda w: ((w >> bit) & jnp.uint32(1)).astype(jnp.int32))
+
+        accs = sweep.reduce_slots(cols_ref, C, L, slot_value, jnp.maximum,
+                                  0, sweep.LANES, jnp.int32)
+        red = sweep.pack_rows([a.max(axis=-1, keepdims=True) for a in accs],
+                              C, jnp.int32)              # OR of 0/1 bits
+        cur = out_ref[pl.ds(row, 1), :]
+        out_ref[pl.ds(row, 1), :] = jnp.maximum(cur, red)
 
 
-def spmv_packed_grid_spec(T, C, L, w_shape, chunk_blk):
+def spmv_packed_grid_spec(S, C, L, w_shape, chunk_blk):
     """The packed-SpMV grid contract, shared by the wrapper and its
     registered cases. Identical tile/output structure to the scalar SpMV;
-    only the frontier operand shrinks to the packed word vector."""
-    tile_spec = pl.BlockSpec((1, C, L), lambda t, tids, rb, na: (tids[t], 0, 0))
+    only the frontier operand shrinks to the packed word rows."""
+    block = pl.BlockSpec((chunk_blk, C),
+                         lambda t, st, ch, lv: (ch[t] // chunk_blk, 0))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(T,),
-        in_specs=[tile_spec,
-                  pl.BlockSpec(w_shape, lambda t, tids, rb, na: (0,))],
-        out_specs=pl.BlockSpec((chunk_blk, C),
-                               lambda t, tids, rb, na: (rb[tids[t]] // chunk_blk, 0)),
+        grid=(S,),
+        in_specs=[sweep.tile_spec(C, L, lambda t, st, ch, lv: (st[t], 0, 0)),
+                  sweep.resident_spec(w_shape, lambda t, st, ch, lv: (0, 0)),
+                  block],
+        out_specs=block,
     )
 
 
 def _spmv_packed_cases():
     d = demo_layout()
     T, C, L, cb = d["T"], d["C"], d["L"], d["chunk_blk"]
-    W = packing.packed_words(d["n_pad"])
-    cases = []
-    for scen, ids, n_active in d["scenarios"]:
-        cases.append(KernelCase(
-            name=f"spmv_packed/{scen}",
-            grid_spec=spmv_packed_grid_spec(T, C, L, (W,), cb),
-            scalar_args=(ids, d["row_block"], n_active),
-            in_shapes=[(T, C, L), (W,)],
-            out_shapes=[(d["n_blk"] * cb, C)],
-            chunked_out=[("out", 0)],
-        ))
-    return cases
+    out_shape = (d["n_blk"] * cb, C)
+    return [KernelCase(
+        name=f"spmv_packed/{scen}",
+        grid_spec=spmv_packed_grid_spec(T, C, L, d["x_rows"], cb),
+        scalar_args=(ids, d["row_block"][ids], n_active),
+        in_shapes=[(T, C, L), d["x_rows"], out_shape],
+        out_shapes=[out_shape],
+        lockstep=[(("in", 2), ("out", 0))],
+        chunked_out=[("out", 0)],
+    ) for scen, ids, n_active in d["scenarios"]]
 
 
 @kernel_contract(_spmv_packed_cases)
@@ -121,66 +117,64 @@ def slimsell_spmv_packed_pallas(cols, tile_ids, row_block, n_active, x_words,
     interpret = resolve_interpret(interpret)
     T, C, L = cols.shape
     n_blk = -(-n_chunks // chunk_blk)
-    grid_spec = spmv_packed_grid_spec(T, C, L, x_words.shape, chunk_blk)
-    kernel = functools.partial(_spmv_packed_kernel, chunk_blk=chunk_blk)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blk * chunk_blk, C), jnp.int32),
+    out_shape = (n_blk * chunk_blk, C)
+    w_rows = sweep.as_rows(x_words, 0)
+    limit = sweep.vmem_limit(
+        "slimsell_spmv_packed_pallas", [(w_rows.shape, jnp.uint32)],
+        [((chunk_blk, C), jnp.int32)] * 2)
+    call = pl.pallas_call(
+        functools.partial(_spmv_packed_kernel, chunk_blk=chunk_blk),
+        grid_spec=spmv_packed_grid_spec(sweep.segment_length(T), C, L,
+                                        w_rows.shape, chunk_blk),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.int32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
-    )(tile_ids, row_block, n_active, cols, x_words)
+    )
+    init = jnp.zeros(out_shape, jnp.int32)
+    return sweep.run_segments(call, tile_ids, row_block, n_active, init,
+                              (cols, w_rows))
 
 
-def _spmm_packed_kernel(tile_ids_ref, row_block_ref, n_active_ref,
-                        cols_ref, x_ref, out_ref, *, chunk_blk: int):
+def _spmm_packed_kernel(steps_ref, chunks_ref, live_ref, cols_ref, x_ref,
+                        acc_ref, out_ref, *, chunk_blk: int):
     """One grid step = one SlimSell tile of the packed-plane SpMM: the RHS
-    rows are uint32 words (32 roots each); OR accumulates whole words."""
+    rows are uint32 words (32 roots each); OR accumulates whole words.
+    The implicit edge value is the all-ones word, so mul (AND) is a no-op,
+    derived in-register — the packed CMP+BLEND analogue."""
     t = pl.program_id(1)
-    tid = tile_ids_ref[t]
-    chunk = row_block_ref[tid]
-    blk = chunk // chunk_blk
-    prev_tid = tile_ids_ref[jnp.maximum(t - 1, 0)]
-    prev_blk = row_block_ref[prev_tid] // chunk_blk
-    first_visit = (t == 0) | (blk != prev_blk)
+    row = sweep.open_block(t, chunks_ref, acc_ref, out_ref, chunk_blk)
+    _, C, L = cols_ref.shape
+    dt = x_ref.shape[-1]
 
-    @pl.when(first_visit)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(t < n_active_ref[0])
+    @pl.when(t < live_ref[0])
     def _work():
-        cols = cols_ref[0]                                  # [C, L]
-        pad = cols < 0
-        safe = jnp.where(pad, 0, cols)
-        xv = x_ref[...]                                     # uint32[n_pad, d_tile]
-        g = jnp.take(xv, safe.reshape(-1), axis=0)          # [C*L, d_tile]
-        g = g.reshape(*cols.shape, xv.shape[-1])            # [C, L, d_tile]
-        # implicit edge value = the all-ones word: mul (AND) is a no-op,
-        # derived in-register — the packed CMP+BLEND analogue
-        contrib = jnp.where(pad[..., None], jnp.asarray(0, jnp.uint32), g)
-        # OR fold over the (static) column-slot axis, unrolled: lane axis
-        # stays the minor word-tile axis so the fold is pure VPU ORs
-        red = contrib[:, 0]
-        for i in range(1, contrib.shape[1]):
-            red = jnp.bitwise_or(red, contrib[:, i])         # [C, d_tile]
-        row = chunk % chunk_blk
-        cur = pl.load(out_ref, (pl.ds(row, 1), slice(None), slice(None)))
-        pl.store(out_ref, (pl.ds(row, 1), slice(None), slice(None)),
-                 jnp.bitwise_or(cur, red[None]))
+        def slot_value(col, c, l):
+            v = x_ref[pl.ds(jnp.maximum(col, 0), 1), :]         # [1, dt]
+            return jnp.where(col >= 0, v, jnp.uint32(0))
+
+        accs = sweep.reduce_slots(cols_ref, C, L, slot_value,
+                                  jnp.bitwise_or, 0, dt, jnp.uint32)
+        red = jnp.concatenate(accs, axis=0)[None]              # [1, C, dt]
+        cur = out_ref[pl.ds(row, 1)]
+        out_ref[pl.ds(row, 1)] = jnp.bitwise_or(cur, red)
 
 
-def spmm_packed_grid_spec(T, C, L, n, d, d_tile, chunk_blk):
-    """The packed-SpMM grid contract. Grid (d // d_tile, T): the tile axis
+def spmm_packed_grid_spec(S, C, L, n, d, d_tile, chunk_blk):
+    """The packed-SpMM grid contract. Grid (d // d_tile, S): the tile axis
     is LAST so SlimChunk revisits stay contiguous within each word tile."""
-    tile_spec = pl.BlockSpec((1, C, L), lambda dt, t, tids, rb, na: (tids[t], 0, 0))
+    block = pl.BlockSpec(
+        (chunk_blk, C, d_tile),
+        lambda dt, t, st, ch, lv: (ch[t] // chunk_blk, 0, dt))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(d // d_tile, T),
-        in_specs=[tile_spec,
-                  pl.BlockSpec((n, d_tile), lambda dt, t, tids, rb, na: (0, dt))],
-        out_specs=pl.BlockSpec(
-            (chunk_blk, C, d_tile),
-            lambda dt, t, tids, rb, na: (rb[tids[t]] // chunk_blk, 0, dt)),
+        grid=(d // d_tile, S),
+        in_specs=[sweep.tile_spec(C, L,
+                                  lambda dt, t, st, ch, lv: (st[t], 0, 0)),
+                  sweep.resident_spec((n, d_tile),
+                                      lambda dt, t, st, ch, lv: (0, dt)),
+                  block],
+        out_specs=block,
     )
 
 
@@ -188,25 +182,24 @@ def _spmm_packed_cases():
     d = demo_layout()
     T, C, L, cb = d["T"], d["C"], d["L"], d["chunk_blk"]
     n, width, d_tile = d["n_pad"], 2, 1   # 2 word planes: exercises revisit
-    cases = []
-    for scen, ids, n_active in d["scenarios"]:
-        cases.append(KernelCase(
-            name=f"spmm_packed/{scen}",
-            grid_spec=spmm_packed_grid_spec(T, C, L, n, width, d_tile, cb),
-            scalar_args=(ids, d["row_block"], n_active),
-            in_shapes=[(T, C, L), (n, width)],
-            out_shapes=[(d["n_blk"] * cb, C, width)],
-            chunked_out=[("out", 0)],
-        ))
-    return cases
+    out_shape = (d["n_blk"] * cb, C, width)
+    return [KernelCase(
+        name=f"spmm_packed/{scen}",
+        grid_spec=spmm_packed_grid_spec(T, C, L, n, width, d_tile, cb),
+        scalar_args=(ids, d["row_block"][ids], n_active),
+        in_shapes=[(T, C, L), (n, width), out_shape],
+        out_shapes=[out_shape],
+        lockstep=[(("in", 2), ("out", 0))],
+        chunked_out=[("out", 0)],
+    ) for scen, ids, n_active in d["scenarios"]]
 
 
 @kernel_contract(_spmm_packed_cases)
 @functools.partial(jax.jit, static_argnames=("chunk_blk", "n_chunks",
-                                             "d_tile", "interpret"))
+                                             "interpret"))
 def slimsell_spmm_packed_pallas(cols, tile_ids, row_block, n_active, X_words,
                                 *, n_chunks: int, chunk_blk: int = 8,
-                                d_tile: int = 128, interpret=None):
+                                interpret=None):
     """Tile-level packed-plane SpMM. Returns y_blocks uint32[n_chunks_pad,
     C, Wb] (chunk-row space).
 
@@ -219,15 +212,24 @@ def slimsell_spmm_packed_pallas(cols, tile_ids, row_block, n_active, X_words,
     interpret = resolve_interpret(interpret)
     T, C, L = cols.shape
     n, d = X_words.shape
-    d_tile = min(d_tile, d)
-    if d % d_tile:
-        d_tile = math.gcd(d, d_tile)
+    d_pad, d_tile = sweep.lane_tile(d)
+    X_words = jnp.pad(X_words, ((0, 0), (0, d_pad - d)))
     n_blk = -(-n_chunks // chunk_blk)
-    grid_spec = spmm_packed_grid_spec(T, C, L, n, d, d_tile, chunk_blk)
-    kernel = functools.partial(_spmm_packed_kernel, chunk_blk=chunk_blk)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blk * chunk_blk, C, d), jnp.uint32),
+    out_shape = (n_blk * chunk_blk, C, d_pad)
+    block = (chunk_blk, C, d_tile)
+    limit = sweep.vmem_limit(
+        "slimsell_spmm_packed_pallas", [((n, d_tile), jnp.uint32)],
+        [(block, jnp.uint32)] * 2)
+    call = pl.pallas_call(
+        functools.partial(_spmm_packed_kernel, chunk_blk=chunk_blk),
+        grid_spec=spmm_packed_grid_spec(sweep.segment_length(T), C, L, n,
+                                        d_pad, d_tile, chunk_blk),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.uint32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
-    )(tile_ids, row_block, n_active, cols, X_words)
+    )
+    init = jnp.zeros(out_shape, jnp.uint32)
+    out = sweep.run_segments(call, tile_ids, row_block, n_active, init,
+                             (cols, X_words))
+    return out[..., :d]

@@ -25,7 +25,8 @@ computes the full reduction and is the oracle for that contract.
 
 SlimWork composes unchanged: the wrapper compacts active tile ids into
 ``tile_ids`` (scalar-prefetch grid indirection; inactive tail repeats the
-last active id, so skipped steps issue no DMA).
+last active id, so skipped steps issue no DMA). Frontier reads, segments
+and the VMEM bound are the SpMV kernel's (``kernels/sweep.py``).
 
 ``slimsell_pull_mm_pallas`` is the **batched** (matrix-RHS) variant for
 multi-source traversal: the frontier is [n, B], the not-final bitmap gains
@@ -38,7 +39,6 @@ dimension carries the batch (d_tile = 128), matching the SpMM kernel.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -47,60 +47,49 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.registry import KernelCase, demo_layout, kernel_contract
 from repro.core.options import resolve_interpret
+from . import sweep
 from .slimsell_spmv import _reduce_l, semiring_ops
 
 
-def _pull_kernel(tile_ids_ref, row_block_ref, n_active_ref,
-                 cols_ref, nf_ref, x_ref, out_ref, *,
-                 sr_name: str, chunk_blk: int):
+def _pull_kernel(steps_ref, chunks_ref, live_ref, cols_ref, nf_ref, x_ref,
+                 acc_ref, out_ref, *, sr_name: str, chunk_blk: int):
     add, contrib_fn, zero = semiring_ops(sr_name)
     t = pl.program_id(0)
-    tid = tile_ids_ref[t]
-    chunk = row_block_ref[tid]
-    blk = chunk // chunk_blk
-
-    prev_tid = tile_ids_ref[jnp.maximum(t - 1, 0)]
-    prev_blk = row_block_ref[prev_tid] // chunk_blk
-    first_visit = (t == 0) | (blk != prev_blk)
-
-    @pl.when(first_visit)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, zero)
-
-    row = chunk % chunk_blk
-    cur = pl.load(out_ref, (pl.ds(row, 1), slice(None)))      # [1, C]
-    nf = pl.load(nf_ref, (pl.ds(row, 1), slice(None)))        # [1, C] int32
+    row = sweep.open_block(t, chunks_ref, acc_ref, out_ref, chunk_blk)
+    _, C, L = cols_ref.shape
+    cur = out_ref[pl.ds(row, 1), :]                           # [1, C]
+    nf = nf_ref[pl.ds(row, 1), :]                             # [1, C] int32
     # pending == still needs a parent: not final and no hit from earlier tiles
     pending = (nf > 0) & (cur == jnp.asarray(zero, cur.dtype))
 
-    @pl.when((t < n_active_ref[0]) & jnp.any(pending))
+    @pl.when((t < live_ref[0]) & jnp.any(pending))
     def _work():
-        cols = cols_ref[0]                                    # [C, L]
-        pad = cols < 0
-        safe = jnp.where(pad, 0, cols)
-        xv = x_ref[...]                                       # frontier, VMEM
-        g = jnp.take(xv, safe.reshape(-1), axis=0).reshape(cols.shape)
-        contrib = jnp.where(pad, jnp.asarray(zero, xv.dtype), contrib_fn(g))
-        red = _reduce_l(sr_name, contrib)                     # [C]
-        new = jnp.where(pending[0], add(cur[0], red), cur[0])
-        pl.store(out_ref, (pl.ds(row, 1), slice(None)), new[None])
+        accs = sweep.reduce_slots(
+            cols_ref, C, L,
+            lambda col, c, l: sweep.lane_select(x_ref, col, zero, contrib_fn),
+            add, zero, sweep.LANES, x_ref.dtype)
+        red = sweep.pack_rows([_reduce_l(sr_name, a)[:, None] for a in accs],
+                              C, x_ref.dtype)
+        out_ref[pl.ds(row, 1), :] = jnp.where(pending, add(cur, red), cur)
 
 
-def pull_grid_spec(T, C, L, x_shape, chunk_blk):
+def pull_grid_spec(S, C, L, x_shape, chunk_blk):
     """The pull-sweep grid contract, shared by the wrapper and its
-    registered contract cases. The not-final bitmap block is mapped in
-    lockstep with the output block (same chunk-row space)."""
+    registered contract cases. The not-final bitmap block and the
+    running-partial input are mapped in lockstep with the output block
+    (same chunk-row space)."""
+    block = pl.BlockSpec((chunk_blk, C),
+                         lambda t, st, ch, lv: (ch[t] // chunk_blk, 0))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(T,),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, C, L), lambda t, tids, rb, na: (tids[t], 0, 0)),
-            pl.BlockSpec((chunk_blk, C),
-                         lambda t, tids, rb, na: (rb[tids[t]] // chunk_blk, 0)),
-            pl.BlockSpec(x_shape, lambda t, tids, rb, na: (0,)),
+            sweep.tile_spec(C, L, lambda t, st, ch, lv: (st[t], 0, 0)),
+            block,
+            sweep.resident_spec(x_shape, lambda t, st, ch, lv: (0, 0)),
+            block,
         ],
-        out_specs=pl.BlockSpec((chunk_blk, C),
-                               lambda t, tids, rb, na: (rb[tids[t]] // chunk_blk, 0)),
+        out_specs=block,
     )
 
 
@@ -110,11 +99,11 @@ def _pull_cases():
     nf_rows = d["n_blk"] * cb
     return [KernelCase(
         name=f"pull/{scen}",
-        grid_spec=pull_grid_spec(T, C, L, (d["n_pad"],), cb),
-        scalar_args=(ids, d["row_block"], n_active),
-        in_shapes=[(T, C, L), (nf_rows, C), (d["n_pad"],)],
+        grid_spec=pull_grid_spec(T, C, L, d["x_rows"], cb),
+        scalar_args=(ids, d["row_block"][ids], n_active),
+        in_shapes=[(T, C, L), (nf_rows, C), d["x_rows"], (nf_rows, C)],
         out_shapes=[(nf_rows, C)],
-        lockstep=[(("in", 1), ("out", 0))],
+        lockstep=[(("in", 1), ("out", 0)), (("in", 3), ("out", 0))],
         chunked_out=[("out", 0)],
     ) for scen, ids, n_active in d["scenarios"]]
 
@@ -125,7 +114,8 @@ def _pull_cases():
 def slimsell_pull_pallas(cols, tile_ids, row_block, n_active, nf, x, *,
                          sr_name: str, n_chunks: int, chunk_blk: int = 8,
                          interpret=None):
-    """Tile-level pull sweep.  Returns y_blocks [n_chunks_pad, C] (chunk-row space).
+    """Tile-level pull sweep.  Returns y_blocks [n_chunks_pad, C] (chunk-row
+    space; chunks no active tile visits hold the semiring zero).
 
     cols:      int32[T, C, L]
     tile_ids:  int32[T]  grid order (SlimWork compaction; tail repeats last)
@@ -137,78 +127,74 @@ def slimsell_pull_pallas(cols, tile_ids, row_block, n_active, nf, x, *,
     interpret = resolve_interpret(interpret)
     T, C, L = cols.shape
     n_blk = -(-n_chunks // chunk_blk)
+    zero = semiring_ops(sr_name)[2]
+    out_shape = (n_blk * chunk_blk, C)
     nf = jnp.pad(nf.astype(jnp.int32),
                  ((0, n_blk * chunk_blk - n_chunks), (0, 0)))
-    grid_spec = pull_grid_spec(T, C, L, x.shape, chunk_blk)
-    kernel = functools.partial(_pull_kernel, sr_name=sr_name, chunk_blk=chunk_blk)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blk * chunk_blk, C), x.dtype),
+    x_rows = sweep.as_rows(x, zero)
+    limit = sweep.vmem_limit(
+        "slimsell_pull_pallas", [(x_rows.shape, x.dtype)],
+        [((chunk_blk, C), jnp.int32)] + [((chunk_blk, C), x.dtype)] * 2)
+    call = pl.pallas_call(
+        functools.partial(_pull_kernel, sr_name=sr_name, chunk_blk=chunk_blk),
+        grid_spec=pull_grid_spec(sweep.segment_length(T), C, L, x_rows.shape,
+                                 chunk_blk),
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
-    )(tile_ids, row_block, n_active, cols, nf, x)
+    )
+    init = jnp.full(out_shape, zero, x.dtype)
+    return sweep.run_segments(call, tile_ids, row_block, n_active, init,
+                              (cols, nf, x_rows))
 
 
 # ----------------------------------------------------------- batched variant
 
 
-def _pull_mm_kernel(tile_ids_ref, row_block_ref, n_active_ref,
-                    cols_ref, nf_ref, x_ref, out_ref, *,
-                    sr_name: str, chunk_blk: int):
+def _pull_mm_kernel(steps_ref, chunks_ref, live_ref, cols_ref, nf_ref, x_ref,
+                    acc_ref, out_ref, *, sr_name: str, chunk_blk: int):
     add, contrib_fn, zero = semiring_ops(sr_name)
     t = pl.program_id(1)
-    tid = tile_ids_ref[t]
-    chunk = row_block_ref[tid]
-    blk = chunk // chunk_blk
-
-    prev_tid = tile_ids_ref[jnp.maximum(t - 1, 0)]
-    prev_blk = row_block_ref[prev_tid] // chunk_blk
-    first_visit = (t == 0) | (blk != prev_blk)
-
-    @pl.when(first_visit)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, zero)
-
-    row = chunk % chunk_blk
-    sl = (pl.ds(row, 1), slice(None), slice(None))
-    cur = pl.load(out_ref, sl)                           # [1, C, dt]
-    nf = pl.load(nf_ref, sl)                             # [1, C, dt] int32
+    row = sweep.open_block(t, chunks_ref, acc_ref, out_ref, chunk_blk)
+    _, C, L = cols_ref.shape
+    dt = x_ref.shape[-1]
+    cur = out_ref[pl.ds(row, 1)]                         # [1, C, dt]
+    nf = nf_ref[pl.ds(row, 1)]                           # [1, C, dt] int32
     # pending per (row, column): not final and no hit from earlier tiles
     pending = (nf > 0) & (cur == jnp.asarray(zero, cur.dtype))
 
-    @pl.when((t < n_active_ref[0]) & jnp.any(pending))
+    @pl.when((t < live_ref[0]) & jnp.any(pending))
     def _work():
-        cols = cols_ref[0]                               # [C, L]
-        pad = cols < 0
-        safe = jnp.where(pad, 0, cols)
-        xv = x_ref[...]                                  # [n, dt] frontier
-        g = jnp.take(xv, safe.reshape(-1), axis=0)       # [C*L, dt]
-        g = g.reshape(*cols.shape, xv.shape[-1])         # [C, L, dt]
-        contrib = jnp.where(pad[..., None], jnp.asarray(zero, xv.dtype),
-                            contrib_fn(g))
-        red = _reduce_l(sr_name, contrib.swapaxes(1, 2))  # [C, dt]
-        new = jnp.where(pending[0], add(cur[0], red), cur[0])
-        pl.store(out_ref, sl, new[None])
+        def slot_value(col, c, l):
+            v = contrib_fn(x_ref[pl.ds(jnp.maximum(col, 0), 1), :])
+            return jnp.where(col >= 0, v, jnp.asarray(zero, v.dtype))
+
+        accs = sweep.reduce_slots(cols_ref, C, L, slot_value, add, zero, dt,
+                                  x_ref.dtype)
+        red = jnp.concatenate(accs, axis=0)[None]       # [1, C, dt]
+        out_ref[pl.ds(row, 1)] = jnp.where(pending, add(cur, red), cur)
 
 
-def pull_mm_grid_spec(T, C, L, n, B, d_tile, chunk_blk):
+def pull_mm_grid_spec(S, C, L, n, B, d_tile, chunk_blk):
     """The batched pull-sweep grid contract, shared by the wrapper and its
     registered contract cases. As in the SpMM, the tile axis is the LAST
-    grid dim; the per-column not-final block rides the output block."""
+    grid dim; the per-column not-final block and the running partial ride
+    the output block."""
+    block = pl.BlockSpec(
+        (chunk_blk, C, d_tile),
+        lambda dt, t, st, ch, lv: (ch[t] // chunk_blk, 0, dt))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B // d_tile, T),
+        grid=(B // d_tile, S),
         in_specs=[
-            pl.BlockSpec((1, C, L),
-                         lambda dt, t, tids, rb, na: (tids[t], 0, 0)),
-            pl.BlockSpec((chunk_blk, C, d_tile),
-                         lambda dt, t, tids, rb, na:
-                         (rb[tids[t]] // chunk_blk, 0, dt)),
-            pl.BlockSpec((n, d_tile), lambda dt, t, tids, rb, na: (0, dt)),
+            sweep.tile_spec(C, L, lambda dt, t, st, ch, lv: (st[t], 0, 0)),
+            block,
+            sweep.resident_spec((n, d_tile),
+                                lambda dt, t, st, ch, lv: (0, dt)),
+            block,
         ],
-        out_specs=pl.BlockSpec(
-            (chunk_blk, C, d_tile),
-            lambda dt, t, tids, rb, na: (rb[tids[t]] // chunk_blk, 0, dt)),
+        out_specs=block,
     )
 
 
@@ -220,23 +206,22 @@ def _pull_mm_cases():
     return [KernelCase(
         name=f"pull_mm/{scen}",
         grid_spec=pull_mm_grid_spec(T, C, L, n, B, d_tile, cb),
-        scalar_args=(ids, d["row_block"], n_active),
-        in_shapes=[(T, C, L), (nf_rows, C, B), (n, B)],
+        scalar_args=(ids, d["row_block"][ids], n_active),
+        in_shapes=[(T, C, L), (nf_rows, C, B), (n, B), (nf_rows, C, B)],
         out_shapes=[(nf_rows, C, B)],
-        lockstep=[(("in", 1), ("out", 0))],
+        lockstep=[(("in", 1), ("out", 0)), (("in", 3), ("out", 0))],
         chunked_out=[("out", 0)],
     ) for scen, ids, n_active in d["scenarios"]]
 
 
 @kernel_contract(_pull_mm_cases)
 @functools.partial(jax.jit, static_argnames=("sr_name", "chunk_blk",
-                                             "n_chunks", "d_tile",
-                                             "interpret"))
+                                             "n_chunks", "interpret"))
 def slimsell_pull_mm_pallas(cols, tile_ids, row_block, n_active, nf, X, *,
                             sr_name: str, n_chunks: int, chunk_blk: int = 8,
-                            d_tile: int = 128, interpret=None):
+                            interpret=None):
     """Batched tile-level pull sweep.  Returns [n_chunks_pad, C, B]
-    (chunk-row space).
+    (chunk-row space; chunks no active tile visits hold the semiring zero).
 
     cols:      int32[T, C, L]
     tile_ids:  int32[T]  grid order (SlimWork compaction; tail repeats last)
@@ -249,22 +234,29 @@ def slimsell_pull_mm_pallas(cols, tile_ids, row_block, n_active, nf, X, *,
     interpret = resolve_interpret(interpret)
     T, C, L = cols.shape
     n, B = X.shape
-    d_tile = min(d_tile, B)
-    if B % d_tile:
-        # widths the lane tiling cannot split evenly (B > 128, B % 128 != 0
-        # — e.g. the distributed engine feeds the raw batch, unlike
-        # multi_source_bfs which rounds up) fall back to the largest
-        # common divisor: correct on every backend, narrower lanes on TPU
-        d_tile = math.gcd(B, d_tile)
+    zero = semiring_ops(sr_name)[2]
+    B_pad, d_tile = sweep.lane_tile(B)
+    X = jnp.pad(X, ((0, 0), (0, B_pad - B)),
+                constant_values=jnp.asarray(zero, X.dtype))
     n_blk = -(-n_chunks // chunk_blk)
     nf = jnp.pad(nf.astype(jnp.int32),
-                 ((0, n_blk * chunk_blk - n_chunks), (0, 0), (0, 0)))
-    grid_spec = pull_mm_grid_spec(T, C, L, n, B, d_tile, chunk_blk)
-    kernel = functools.partial(_pull_mm_kernel, sr_name=sr_name,
-                               chunk_blk=chunk_blk)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blk * chunk_blk, C, B), X.dtype),
+                 ((0, n_blk * chunk_blk - n_chunks), (0, 0), (0, B_pad - B)))
+    out_shape = (n_blk * chunk_blk, C, B_pad)
+    block = (chunk_blk, C, d_tile)
+    limit = sweep.vmem_limit(
+        "slimsell_pull_mm_pallas", [((n, d_tile), X.dtype)],
+        [(block, jnp.int32)] + [(block, X.dtype)] * 2)
+    call = pl.pallas_call(
+        functools.partial(_pull_mm_kernel, sr_name=sr_name,
+                          chunk_blk=chunk_blk),
+        grid_spec=pull_mm_grid_spec(sweep.segment_length(T), C, L, n, B_pad,
+                                    d_tile, chunk_blk),
+        out_shape=jax.ShapeDtypeStruct(out_shape, X.dtype),
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
-    )(tile_ids, row_block, n_active, cols, nf, X)
+    )
+    init = jnp.full(out_shape, zero, X.dtype)
+    out = sweep.run_segments(call, tile_ids, row_block, n_active, init,
+                             (cols, nf, X))
+    return out[..., :B]

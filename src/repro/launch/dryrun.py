@@ -30,7 +30,6 @@ def _analyze(compiled):
 def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str | None,
              save_hlo: bool = False) -> dict:
     import jax
-    from repro.compat import set_mesh
     from repro.configs import build_cell, get as get_arch
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import collective_bytes, roofline
@@ -44,7 +43,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str | None,
     family = get_arch(arch).FAMILY
     try:
         cell = build_cell(arch, shape, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(cell.fn).lower(*cell.args)
             t_lower = time.time()
             compiled = lowered.compile()
@@ -64,7 +63,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str | None,
         if family == "lm":
             method = "layer-extrapolation"
             L = get_arch(arch).make_config().n_layers
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 c1 = build_cell(arch, shape, mesh, cost_layers=1)
                 comp1 = jax.jit(c1.fn).lower(*c1.args).compile()
                 cost1, coll1 = _analyze(comp1)

@@ -10,17 +10,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.compat import make_mesh, mesh_from_devices
+from repro.core.dist_bfs import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_host_mesh(shape: tuple, axes: tuple) -> Mesh:
-    """Small mesh over host CPU devices (tests)."""
     return make_mesh(shape, axes)
 
 
@@ -38,4 +33,4 @@ def remesh(failed_devices: set, *, axes=("data", "model")) -> Mesh:
         model -= 1
     data = n // model
     grid = np.array(devices[: data * model]).reshape(data, model)
-    return mesh_from_devices(grid, axes)
+    return Mesh(grid, axes)

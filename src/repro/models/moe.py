@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 Array = jax.Array
 
@@ -151,7 +150,7 @@ def moe_ep_train(x: Array, w_router: Array, wi_g: Array, wi_u: Array,
              gates[..., None].astype(pair_out.dtype)).sum(axis=1)
         return y.reshape(Bl, Sl, D).astype(x_loc.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(dp, tp, None), P(), w_spec_in, w_spec_in, w_spec_out),
         out_specs=P(dp, tp, None),
@@ -214,7 +213,7 @@ def moe_ep_decode(x: Array, w_router: Array, wi_g: Array, wi_u: Array,
         y = jax.lax.psum(y, tp)
         return y.reshape(Bl, Sl, D).astype(x_loc.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(b_axes, None, None), P(), w_spec_in, w_spec_in,
                   w_spec_out),

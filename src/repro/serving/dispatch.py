@@ -50,13 +50,12 @@ import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core import engine as eng
 from ..core.betweenness import betweenness
-from ..core.bfs import dp_transform
+from ..core.bfs import columnwise, dp_transform
 from ..core.cc import CC_SPEC, cc
 from ..core.formats import layout_signature
 from ..core.khop import khop_many
@@ -407,13 +406,11 @@ class Dispatcher:
             if need_dp and sem == "selmax":
                 p_all = np.asarray(state["p"].astype(jnp.int32) - 1).T
             elif need_dp:
-                # one vmapped DP sweep serves every column (mirrors
-                # multi_source_bfs — per-column eager sweeps would dominate
-                # the harvest)
-                p_all = np.asarray(jax.vmap(
-                    dp_transform, in_axes=(None, 1, 0))(
-                        self.tiled, state["d"],
-                        jnp.asarray(slot.roots())))
+                # one on-device loop of DP sweeps serves every column
+                # (mirrors multi_source_bfs — per-column eager calls would
+                # dominate the harvest)
+                p_all = np.asarray(columnwise(
+                    dp_transform, self.tiled, state["d"], slot.roots()))
             for col, q in enumerate(slot.queries):
                 parents = None
                 if q.need_parents:
@@ -428,9 +425,8 @@ class Dispatcher:
         col_buckets = np.asarray(state["buckets"])
         p_all = None
         if need_dp:
-            p_all = np.asarray(jax.vmap(
-                sssp_parents, in_axes=(None, 1, 0))(
-                    self.tiled, state["dist"], jnp.asarray(slot.roots())))
+            p_all = np.asarray(columnwise(
+                sssp_parents, self.tiled, state["dist"], slot.roots()))
         for col, q in enumerate(slot.queries):
             parents = p_all[col] if q.need_parents else None
             self._finish(q, values=d[col], parents=parents,

@@ -13,11 +13,13 @@ def run_multidevice(script: str, n_devices: int = 8, timeout: int = 600):
     """Run a snippet in a subprocess with N forced host devices.
 
     Tests that need >1 device use this so the main pytest process keeps the
-    default single-device view (the dry-run owns the 512-device flag).
+    default single-device view (the dry-run owns the 512-device flag). The
+    child is held to the CPU: the forced device count only applies there,
+    and a parent may already hold the TPU library.
     """
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
-               PYTHONPATH=SRC)
+               JAX_PLATFORMS="cpu", PYTHONPATH=SRC)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=timeout, env=env)
     if r.returncode != 0:

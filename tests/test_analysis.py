@@ -46,17 +46,18 @@ def test_contract_rejects_oob_index_map():
     from repro.kernels.slimsell_spmv import spmv_grid_spec
     d = demo_layout()
     T, C, L, cb = d["T"], d["C"], d["L"], d["chunk_blk"]
+    out_shape = (d["n_blk"] * cb, C)
     # a corrupt row_block points one tile at chunk 99 -> output block 49,
     # far beyond the 3 existing blocks; Pallas would silently clamp
     bad_rb = d["row_block"].copy()
     bad_rb[4] = 99
+    ids = np.arange(T, dtype=np.int32)
     case = KernelCase(
-        name="bad/oob", grid_spec=spmv_grid_spec(T, C, L, (d["n_pad"],), cb,
+        name="bad/oob", grid_spec=spmv_grid_spec(T, C, L, d["x_rows"], cb,
                                                  False),
-        scalar_args=(np.arange(T, dtype=np.int32), bad_rb,
-                     np.asarray([T], np.int32)),
-        in_shapes=[(T, C, L), (d["n_pad"],)],
-        out_shapes=[(d["n_blk"] * cb, C)],
+        scalar_args=(ids, bad_rb[ids], np.asarray([T], np.int32)),
+        in_shapes=[(T, C, L), d["x_rows"], out_shape],
+        out_shapes=[out_shape],
         chunked_out=[("out", 0)])
     errs = contracts.check_case(case)
     assert any("outside [0," in e and "clamp" in e for e in errs), errs
@@ -66,15 +67,16 @@ def test_contract_rejects_noncontiguous_revisit():
     from repro.kernels.slimsell_spmv import spmv_grid_spec
     d = demo_layout()
     T, C, L, cb = d["T"], d["C"], d["L"], d["chunk_blk"]
+    out_shape = (d["n_blk"] * cb, C)
     # interleave tiles of different chunks: block order 0,1,0,... would
     # make first_visit re-init block 0 twice, dropping tile 0's partial
     ids = np.asarray([0, 3, 1, 2, 4, 5, 6, 7, 8], np.int32)
     case = KernelCase(
         name="bad/interleave",
-        grid_spec=spmv_grid_spec(T, C, L, (d["n_pad"],), cb, False),
-        scalar_args=(ids, d["row_block"], np.asarray([T], np.int32)),
-        in_shapes=[(T, C, L), (d["n_pad"],)],
-        out_shapes=[(d["n_blk"] * cb, C)],
+        grid_spec=spmv_grid_spec(T, C, L, d["x_rows"], cb, False),
+        scalar_args=(ids, d["row_block"][ids], np.asarray([T], np.int32)),
+        in_shapes=[(T, C, L), d["x_rows"], out_shape],
+        out_shapes=[out_shape],
         chunked_out=[("out", 0)])
     errs = contracts.check_case(case)
     assert any("revisited non-contiguously" in e for e in errs), errs
@@ -288,6 +290,23 @@ def test_interpret_default_env_override(monkeypatch):
         options.default_interpret()
 
 
+
+def test_interpret_forced_on_tpu_raises(monkeypatch):
+    # on a TPU the kernels run compiled or not at all: every way of forcing
+    # interpret mode fails loudly instead of running slowly
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv(options.INTERPRET_ENV, raising=False)
+    assert options.default_interpret() is False
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        options.resolve_interpret(True)
+    monkeypatch.setenv(options.INTERPRET_ENV, "1")
+    with pytest.raises(RuntimeError, match=options.INTERPRET_ENV):
+        options.default_interpret()
+    monkeypatch.delenv(options.INTERPRET_ENV)
+    with options.interpret_override(True):
+        with pytest.raises(RuntimeError, match="interpret mode"):
+            options.default_interpret()
+
 # ------------------------------------------------------------ sanitizer mode
 
 
@@ -387,7 +406,7 @@ def test_sanitizer_enable_disable_and_suspend():
 def test_sanitized_distributed_bfs():
     run_multidevice("""
 import numpy as np
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.core import debug
 from repro.core.dist_bfs import partition_slimsell, make_dist_bfs
 from repro.graphs.generators import kronecker

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.launch import train as train_launch
 
 

@@ -10,7 +10,7 @@ from conftest import run_multidevice
 
 _PRELUDE = """
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.graphs.generators import (erdos_renyi, kronecker, star,
                                      two_components, with_random_weights)
 from repro.core.dist_bfs import (partition_slimsell, make_dist_bfs,
@@ -100,7 +100,7 @@ def test_dist_multi_sssp_parity():
     distance matrix: every column matches Dijkstra and the single-device
     batched engine (same per-column sweeps/buckets — the per-column phase
     machines are shared), on both local-sweep backends, with a batch width
-    the 128-lane tile does not divide (gcd fallback)."""
+    the 128-lane tile does not divide."""
     run_multidevice(_PRELUDE + """
 from repro.core.sssp import dijkstra_reference
 from repro.core.multi_sssp import multi_source_sssp
@@ -170,7 +170,7 @@ for dirn in ["push", "pull"]:
                        backend="pallas", direction=dirn)
     d, it = fn(dist.cols, dist.row_block, dist.row_vertex, np.int32(0))
     assert np.array_equal(np.asarray(d), d_ref), ("pallas", dirn)
-# odd batch width exercises the kernels' gcd lane fallback on the mesh
+# odd batch width: the kernels run it as one full-width lane tile
 roots = np.asarray([0, 3, 9, 22, 41], np.int32)
 refs = np.stack([bfs_traditional(csr, int(r))[0] for r in roots])
 for dirn in ["push", "pull"]:

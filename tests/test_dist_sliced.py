@@ -7,7 +7,7 @@ def test_sliced_bfs_matches_oracle_2d_and_3d():
     run_multidevice("""
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.graphs.generators import kronecker
 from repro.core.formats import sellcs_order
 from repro.core.dist_bfs import partition_slimsell, make_dist_bfs_sliced

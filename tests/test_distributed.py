@@ -8,7 +8,7 @@ from conftest import run_multidevice
 def test_dist_bfs_all_semirings_8dev():
     run_multidevice("""
 import numpy as np, jax
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.graphs.generators import kronecker
 from repro.core.dist_bfs import partition_slimsell, make_dist_bfs
 from repro.core.bfs_traditional import bfs_traditional
@@ -28,7 +28,7 @@ print("PASS")
 def test_dist_bfs_multipod_axes():
     run_multidevice("""
 import numpy as np, jax
-from repro.compat import make_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.graphs.generators import erdos_renyi
 from repro.core.dist_bfs import partition_slimsell, make_dist_bfs
 from repro.core.bfs_traditional import bfs_traditional
@@ -47,7 +47,7 @@ print("PASS")
 def test_moe_ep_matches_reference_4dev():
     run_multidevice("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh, set_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.models import moe as moe_lib
 mesh = make_mesh((2, 2), ("data", "model"))
 dims = moe_lib.MoEDims(n_experts=8, top_k=2, d_model=16, d_ff=32,
@@ -59,7 +59,7 @@ wig = jax.random.normal(ks[2], (8, 16, 32)) * 0.1
 wiu = jax.random.normal(ks[3], (8, 16, 32)) * 0.1
 wo = jax.random.normal(ks[4], (8, 32, 16)) * 0.1
 y_ref = moe_lib.moe_reference(x, wr, wig, wiu, wo, dims)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep = moe_lib.moe_ep_train(x, wr, wig, wiu, wo, dims, mesh,
                                 dp=("data",), tp="model", fsdp=("data",))
     y_dec = moe_lib.moe_ep_decode(x[:, :1], wr, wig, wiu, wo, dims, mesh,
@@ -74,7 +74,7 @@ print("PASS")
 def test_sharded_lm_train_step_matches_single_device():
     run_multidevice("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh, set_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.models import transformer as tf
 from repro.models.sharding import AxisRules
 cfg = tf.LMConfig(name="t", n_layers=2, d_model=32, n_heads=4, n_kv=2,
@@ -86,7 +86,7 @@ batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
 l0 = tf.loss_fn(params, batch, cfg, None)
 mesh = make_mesh((2, 2), ("data", "model"))
 ctx = tf.ShardCtx(mesh=mesh, rules=AxisRules.for_mesh(mesh))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     l1 = jax.jit(lambda p, b: tf.loss_fn(p, b, cfg, ctx))(params, batch)
 assert abs(float(l0) - float(l1)) < 1e-3, (float(l0), float(l1))
 print("PASS")
@@ -97,7 +97,7 @@ def test_context_parallel_attention_matches_single_device():
     """Arch with heads not divisible by tp -> context-parallel path."""
     run_multidevice("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh, set_mesh
+from repro.core.dist_bfs import make_mesh
 from repro.models import transformer as tf
 from repro.models.sharding import AxisRules
 cfg = tf.LMConfig(name="t", n_layers=2, d_model=30, n_heads=3, n_kv=3,
@@ -110,7 +110,7 @@ l0 = tf.loss_fn(params, batch, cfg, None)
 mesh = make_mesh((2, 2), ("data", "model"))
 ctx = tf.ShardCtx(mesh=mesh, rules=AxisRules.for_mesh(mesh))
 assert tf._attn_mode(cfg, ctx) == "context"
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     l1 = jax.jit(lambda p, b: tf.loss_fn(p, b, cfg, ctx))(params, batch)
 assert abs(float(l0) - float(l1)) < 1e-3, (float(l0), float(l1))
 print("PASS")

@@ -34,7 +34,7 @@ def random_layout(kind: str, seed: int):
     return csr, build_slimsell(csr, C=8, L=16).to_jax()
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(kind=st.sampled_from(["kron", "er", "ring"]),
        seed=st.integers(min_value=0, max_value=31),
        damping=st.floats(min_value=0.05, max_value=0.95),
@@ -49,7 +49,7 @@ def test_pagerank_conserves_mass(kind, seed, damping, sweeps):
     assert np.all(res.ranks >= 0)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(n=st.integers(min_value=3, max_value=40))
 def test_betweenness_path_closed_form(n):
     from repro.core.betweenness import betweenness
@@ -61,7 +61,7 @@ def test_betweenness_path_closed_form(n):
                                rtol=1e-5, atol=1e-6)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(kind=st.sampled_from(["kron", "er", "ring"]),
        seed=st.integers(min_value=0, max_value=31),
        root=st.integers(min_value=0, max_value=63))
@@ -74,7 +74,7 @@ def test_khop_unbounded_is_reachability(kind, seed, root):
     np.testing.assert_array_equal(res.distances, d_bfs)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(kind=st.sampled_from(["kron", "er", "ring"]),
        seed=st.integers(min_value=0, max_value=31),
        root=st.integers(min_value=0, max_value=63),
@@ -90,7 +90,7 @@ def test_khop_balls_nested(kind, seed, root, k):
     assert np.all(outer.distances[outer.mask & ~inner.mask] == k + 1)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(kind=st.sampled_from(["kron", "er", "ring"]),
        seed=st.integers(min_value=0, max_value=31),
        root=st.integers(min_value=0, max_value=63),

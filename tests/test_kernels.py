@@ -94,3 +94,44 @@ def test_spmv_kernel_grid_indirection_matches_dense_grid(rng):
     y1 = ops.spmv("tropical", tiled, x,
                   tile_mask=jnp.ones(tiled.n_tiles, bool))
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+
+
+@pytest.mark.parametrize("kernel", ["spmv", "pull", "spmm", "pull_mm",
+                                    "spmv_packed", "spmm_packed"])
+def test_segmented_grid_matches_single_call(kernel, rng, monkeypatch):
+    """Tile lists longer than one pallas_call's grid run in segments that
+    accumulate through the aliased output: a chunk straddling a segment
+    boundary keeps its partial, and segments with no active step are
+    skipped. Segmenting must not change a single bit."""
+    from repro.core import packing
+    from repro.kernels import sweep
+    csr = kronecker(7, 8, seed=12)
+    tiled = build_slimsell(csr, C=8, L=16).to_jax()
+    n = csr.n
+    x = _frontier("tropical", n, rng)
+    X = jnp.stack([x, jnp.roll(x, 3)], axis=1)
+    bits = jnp.asarray(rng.random(n) < 0.3)
+    mask = jnp.asarray(rng.random(tiled.n_tiles) < 0.6)
+    call = {
+        "spmv": lambda: ops.spmv("tropical", tiled, x, tile_mask=mask),
+        "pull": lambda: ops.pull("tropical", tiled, x, bits, tile_mask=mask),
+        "spmm": lambda: ops.spmm("tropical", tiled, X, tile_mask=mask),
+        "pull_mm": lambda: ops.pull_mm("tropical", tiled, X,
+                                       jnp.stack([bits, ~bits], axis=1),
+                                       tile_mask=mask),
+        "spmv_packed": lambda: ops.spmv_packed(
+            tiled, packing.pack_bits(bits), tile_mask=mask),
+        "spmm_packed": lambda: ops.spmm_packed(
+            tiled, packing.pack_bits(jnp.stack([bits, ~bits], axis=1)),
+            tile_mask=mask),
+    }[kernel]
+    jax.clear_caches()
+    whole = np.asarray(call())
+    monkeypatch.setattr(sweep, "MAX_GRID_STEPS", 7)
+    assert -(-tiled.n_tiles // sweep.segment_length(tiled.n_tiles)) > 2
+    jax.clear_caches()
+    try:
+        segmented = np.asarray(call())
+    finally:
+        jax.clear_caches()
+    np.testing.assert_array_equal(segmented, whole)

@@ -6,7 +6,7 @@ sweep/bucket counts — the full-weight-operand scheduling argument in
 ``core/multi_sssp.py`` is exact, not approximate) and match the scipy
 Dijkstra oracle across graph families × both backends × both engine modes;
 per-column delta invariance; non-128-divisible batch widths through the
-SpMM kernel's gcd lane-tile fallback; batch splitting/padding; parent
+SpMM kernel's lane padding; batch splitting/padding; parent
 validation; the batched Graph500 harness; and boundary errors.
 """
 import numpy as np
@@ -122,8 +122,7 @@ def test_bellman_ford_single_bucket_every_column():
 
 
 def test_non_lane_divisible_batch_width_pallas():
-    """B = 5 (and a 200-root width > 128 with 128 ∤ B after round-up checks)
-    exercise the SpMM kernel's gcd lane-tile fallback."""
+    """B = 5 runs through the SpMM kernel as one full-width lane tile."""
     csr = FAMILIES["er"]()
     tiled = layout(csr)
     roots = roots_of(csr, k=5, seed=3)
