@@ -1,0 +1,8 @@
+"""Percent of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    t = run.device_trace
+    if run.kind != "open_loop" or not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -1,0 +1,12 @@
+"""Device milliseconds of the parent transform's programs (the columnwise
+``dp_transform`` or ``sssp_parents`` of every batch) in the traced window,
+per key completed in it."""
+from bench.trace import parent_transform_s
+
+
+def read(run):
+    t, done = run.device_trace, run.window.get("completed")
+    if run.kind != "batches" or not t or not done:
+        return None
+    seconds = parent_transform_s(t["programs"])
+    return 1e3 * seconds / done if seconds > 0 else None
