@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 import threading
 from functools import lru_cache, partial
 from typing import Any, Callable, Optional, Sequence
@@ -153,6 +154,20 @@ def _sweep(spec: FixpointSpec, tiled, x, w, tile_mask, rows, backend: str,
     return y
 
 
+def _tile_counts(mask: Optional[Array], n_tiles: Array, backend: str):
+    """(tiles active, tiles read) by one sweep under SlimWork ``mask``.
+
+    Active: the tiles the mask selects, or every tile where there is no
+    mask. Read: the Pallas kernels walk only the selected tiles (the mask
+    becomes their grid), while the jnp gather reads every tile and masks
+    the reduced rows afterwards.
+    """
+    if mask is None:
+        return n_tiles, n_tiles
+    active = mask.sum(dtype=jnp.int32)
+    return active, active if backend == "pallas" else n_tiles
+
+
 def _subset_ctx(ctx, ids: Array, n_tiles: int):
     """Gather the tile-space leaves of a spec ctx down to the active tiles;
     scalars and non-tile leaves pass through untouched."""
@@ -181,7 +196,9 @@ def _fixpoint_loop(spec: FixpointSpec, tiled, ctx, state, *,
 
     ``batch_width`` is the batch axis B for batched specs (callers with the
     init arg in hand derive it; handles bake it in statically). Returns
-    ``(state, iterations, work, dirs, plog)`` — the raw device values.
+    ``(state, iterations, work, dirs, plog, tiles)`` — the raw device
+    values; ``tiles`` is the int32 pair (tiles active, tiles read) summed
+    over the sweeps (``_tile_counts``).
     """
     n = tiled.n
     log_n = WORK_LOG if log_work else 1
@@ -199,11 +216,11 @@ def _fixpoint_loop(spec: FixpointSpec, tiled, ctx, state, *,
                          jnp.int32)
 
     def cond(carry):
-        _, k, cont, _, _, _, _ = carry
+        _, k, cont, _, _, _, _, _ = carry
         return cont & (k <= max_iters)
 
     def body(carry):
-        state, k, _, work, dcur, dirs, plog = carry
+        state, k, _, work, dcur, dirs, plog, tiles = carry
         nf = spec.not_final(ctx, state) if direction != "push" else None
         sb = spec.source_bits(ctx, state, k) if use_push else None
         if direction == "auto":
@@ -235,8 +252,7 @@ def _fixpoint_loop(spec: FixpointSpec, tiled, ctx, state, *,
                            pull=False,
                            n_bits=batch_width if spec.packed else None)
             state, cont = spec.update(ctx, state, y, k)
-            used = mask.sum(dtype=jnp.int32) if (slimwork and mask is not None) \
-                else n_tiles_c
+            used, read = _tile_counts(mask, n_tiles_c, backend)
         else:
             # the tile masks are built INSIDE the branches so the untaken
             # direction's mask is never materialized (lax.cond operands
@@ -245,23 +261,21 @@ def _fixpoint_loop(spec: FixpointSpec, tiled, ctx, state, *,
                 mask = dm.push_tile_mask(tiled, sb) if slimwork else None
                 y = _sweep(spec, tiled, x, w, mask, None, backend, pull=False)
                 st, cont = spec.update(ctx, state, y, k)
-                used = mask.sum(dtype=jnp.int32) if slimwork else n_tiles_c
-                return st, cont, used
+                return (st, cont) + _tile_counts(mask, n_tiles_c, backend)
 
             def pull_fn(state):
                 mask = _pull_tile_mask(tiled, nf) if slimwork else None
                 y = _sweep(spec, tiled, x, w, mask, nf, backend, pull=True)
                 st, cont = spec.update(ctx, state, y, k)
-                used = mask.sum(dtype=jnp.int32) if slimwork else n_tiles_c
-                return st, cont, used
+                return (st, cont) + _tile_counts(mask, n_tiles_c, backend)
 
             if direction == "push":
-                state, cont, used = push_fn(state)
+                state, cont, used, read = push_fn(state)
             elif direction == "pull":
-                state, cont, used = pull_fn(state)
+                state, cont, used, read = pull_fn(state)
             else:
-                state, cont, used = jax.lax.cond(dnext == dm.PUSH, push_fn,
-                                                 pull_fn, state)
+                state, cont, used, read = jax.lax.cond(
+                    dnext == dm.PUSH, push_fn, pull_fn, state)
         if log_work:
             idx = jnp.minimum(k - 1, WORK_LOG - 1)
             if slimwork:
@@ -271,12 +285,14 @@ def _fixpoint_loop(spec: FixpointSpec, tiled, ctx, state, *,
                                                 dtype=jnp.int32))
             else:
                 dirs = dirs.at[idx].set(dnext)
-        return state, k + 1, cont, work, dnext, dirs, plog
+        tiles = (tiles[0] + used, tiles[1] + read)
+        return state, k + 1, cont, work, dnext, dirs, plog, tiles
 
-    state, k, _, work, _, dirs, plog = jax.lax.while_loop(
+    zero = jnp.asarray(0, jnp.int32)
+    state, k, _, work, _, dirs, plog, tiles = jax.lax.while_loop(
         cond, body, (state, jnp.asarray(1, jnp.int32), jnp.asarray(True),
-                     work, d0, dirs, plog))
-    return state, k - 1, work, dirs, plog
+                     work, d0, dirs, plog, (zero, zero)))
+    return state, k - 1, work, dirs, plog, tiles
 
 
 def _run_fused_impl(spec: FixpointSpec, tiled, arg, ctx_args, *,
@@ -289,7 +305,7 @@ def _run_fused_impl(spec: FixpointSpec, tiled, arg, ctx_args, *,
     return _fixpoint_loop(spec, tiled, ctx, state, slimwork=slimwork,
                           max_iters=max_iters, log_work=log_work,
                           backend=backend, direction=direction,
-                          batch_width=width)
+                          batch_width=width)[:5]
 
 
 _run_fused = partial(jax.jit, static_argnames=_FUSED_STATICS)(_run_fused_impl)
@@ -347,10 +363,15 @@ class FixpointHandle:
     (distance/frontier buffers are reused in place on TPU/GPU; donation is
     auto-disabled on CPU where XLA ignores it with a warning).
 
-    ``run`` returns ``(state, iterations)`` as *device* values without
-    blocking — JAX's async dispatch lets the caller overlap host-side
-    request handling with the device sweeps and harvest one step late.
-    Under ``debug.checked()`` the call routes through a checkified twin.
+    ``run`` returns ``(state, iterations, tiles)`` as *device* values
+    without blocking — JAX's async dispatch lets the caller overlap
+    host-side request handling with the device sweeps and harvest one step
+    late. ``tiles`` is the int32 pair (tiles active, tiles read) summed over
+    the sweeps (``_tile_counts``); read it after ``iterations``, which has
+    already waited for the program. The jitted function is named
+    ``fixpoint_<spec name>``, so its XLA program is
+    ``jit_fixpoint_<spec name>`` (``/`` becomes ``_``). Under
+    ``debug.checked()`` the call routes through a checkified twin.
     """
     spec: FixpointSpec
     slimwork: bool
@@ -373,7 +394,8 @@ class FixpointHandle:
         return self.spec.init_state(tiled.n, arg, ctx)
 
     def run(self, tiled, ctx, state):
-        """Drive ``state`` to the fixpoint; async ``(state, iterations)``."""
+        """Drive ``state`` to the fixpoint; async ``(state, iterations,
+        tiles)``."""
         if debug.enabled():
             return debug.call_checked(self._impl, tiled, ctx, state,
                                       pallas=self.backend == "pallas")
@@ -396,12 +418,15 @@ def _fixpoint_handle_cached(spec: FixpointSpec, slimwork: bool,
                             batch_width: Optional[int],
                             donate: bool) -> FixpointHandle:
     def impl(tiled, ctx, state):
-        state, iters, _, _, _ = _fixpoint_loop(
+        state, iters, _, _, _, tiles = _fixpoint_loop(
             spec, tiled, ctx, state, slimwork=slimwork, max_iters=max_iters,
             log_work=False, backend=backend, direction=direction,
             batch_width=batch_width)
-        return state, iters
+        return state, iters, tiles
 
+    # the program's name says which spec ran (jit_fixpoint_<spec name>)
+    impl.__name__ = impl.__qualname__ = \
+        "fixpoint_" + re.sub(r"\W", "_", spec.name)
     jitted = jax.jit(impl, donate_argnums=(2,) if donate else ())
     return FixpointHandle(spec=spec, slimwork=slimwork, max_iters=max_iters,
                           backend=backend, direction=direction,

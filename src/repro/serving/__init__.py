@@ -8,7 +8,8 @@ queries (``submit`` -> ``QueryHandle``), buckets them by execution
 signature (``Batcher``), runs
 them as padded power-of-two device batches on persistent jitted handles
 with async harvest (``Dispatcher``), and reports throughput/latency/fill
-counters (``ServingMetrics`` via ``stats()``).
+counters (``ServingMetrics`` via ``stats()``). Named ``repro.*`` profiler
+spans mark its layer boundaries (``tracing``).
 
 The layer is concurrent and multi-graph: ``GraphSession`` is thread-safe
 (locked batcher/metrics, an optional ``background=True`` flush thread,
@@ -30,7 +31,8 @@ fans one front door out over many resident graphs keyed by
     sess.drain()                       # streamed: shape-bucketed batches
     [h.result() for h in hs]
 """
-from . import batcher, dispatch, metrics, router, session  # noqa: F401
+from . import (batcher, dispatch, metrics, router, session,  # noqa: F401
+               tracing)
 from .batcher import (Batcher, BatchSlot, BucketKey, Query,  # noqa: F401
                       QueueFull)
 from .dispatch import (DeadlineExpired, Dispatcher,  # noqa: F401

@@ -68,6 +68,7 @@ from ..core.pagerank import (PAGERANK_MAX_ITERS, PAGERANK_SPEC, pagerank,
 from ..core.sssp import sssp_parents
 from .batcher import BatchSlot, Query
 from .metrics import ServingMetrics
+from .tracing import qid_list, span
 
 
 class DeadlineExpired(RuntimeError):
@@ -172,8 +173,10 @@ class QueryResult:
 class _Inflight:
     """One launched-but-unharvested fused batch (device buffers inside)."""
     slot: BatchSlot
+    batch: int                        # the dispatcher's batch sequence id
     state: dict                       # device arrays; harvest blocks on them
     iters: object                     # device scalar
+    tiles: tuple                      # device (tiles active, tiles read)
     ctx: Optional[dict]
 
 
@@ -199,6 +202,7 @@ class Dispatcher:
         self._handles: Dict[tuple, eng.FixpointHandle] = {}
         self._layout_sig = layout_signature(tiled)
         self._pr_views = None  # lazy (inv_deg, dangling) for pagerank
+        self._next_batch = 0   # sequence id of the next dispatched slot
 
     def _pagerank_views(self):
         if self._pr_views is None:
@@ -249,12 +253,26 @@ class Dispatcher:
             self._dispatch_locked(slot)
 
     def _dispatch_locked(self, slot: BatchSlot) -> None:
+        batch = self._next_batch
+        self._next_batch += 1
+        with span("dispatch", batch=batch, algorithm=slot.key.algorithm,
+                  width=slot.width, n_real=slot.n_real,
+                  qids=qid_list(q.qid for q in slot.queries)):
+            self._launch(slot, batch)
+        while len(self._inflight) > self.max_inflight:
+            self._harvest_one()
+
+    def _launch(self, slot: BatchSlot, batch: int) -> None:
         cfg, alg = self.config, slot.key.algorithm
         n = self.tiled.n
+        whole_graph = alg in ("cc", "pagerank", "betweenness")
+        now = self._clock()
+        wait = sum(now - q.submitted_at for q in slot.queries)
+        # a whole-graph column serves all its queries: it waited their mean
         self.metrics.inc(
             batches_dispatched=1, columns_total=slot.width,
-            columns_real=(1 if alg in ("cc", "pagerank", "betweenness")
-                          else slot.n_real))
+            columns_real=1 if whole_graph else slot.n_real,
+            queue_wait_s=wait / slot.n_real if whole_graph else wait)
 
         # betweenness is two chained fixpoints with host orchestration
         # between them (level extraction feeds the backward pass), so it
@@ -316,11 +334,9 @@ class Dispatcher:
                     (jnp.asarray(slot.key.delta, jnp.float32),))
                 state = handle.init_state(self.tiled,
                                           jnp.asarray(slot.roots()), ctx)
-            state, iters = handle.run(self.tiled, ctx, state)
-        self._inflight.append(_Inflight(slot=slot, state=state,
-                                        iters=iters, ctx=ctx))
-        while len(self._inflight) > self.max_inflight:
-            self._harvest_one()
+            state, iters, tiles = handle.run(self.tiled, ctx, state)
+        self._inflight.append(_Inflight(slot=slot, batch=batch, state=state,
+                                        iters=iters, tiles=tiles, ctx=ctx))
 
     def drain(self) -> None:
         """Harvest every batch still in flight (blocks on the device)."""
@@ -372,9 +388,18 @@ class Dispatcher:
 
     def _harvest_one(self) -> None:
         fl = self._inflight.popleft()
-        slot, state = fl.slot, fl.state
-        iters = int(fl.iters)            # blocks until the batch is done
-        self.metrics.inc(sweeps_total=iters)
+        with span("harvest", batch=fl.batch,
+                  qids=qid_list(q.qid for q in fl.slot.queries)):
+            with span("harvest.wait"):
+                iters = int(fl.iters)    # blocks until the batch is done
+            # the program has finished: reading its counters adds no wait
+            active, read = (int(t) for t in fl.tiles)
+            self.metrics.inc(sweeps_total=iters, tiles_active_total=active,
+                             tiles_read_total=read)
+            self._harvest_results(fl.slot, fl.state, iters)
+
+    def _harvest_results(self, slot: BatchSlot, state: dict,
+                         iters: int) -> None:
         alg, sem = slot.key.algorithm, slot.key.semiring
 
         if alg == "cc":
@@ -409,8 +434,9 @@ class Dispatcher:
                 # one on-device loop of DP sweeps serves every column
                 # (mirrors multi_source_bfs — per-column eager calls would
                 # dominate the harvest)
-                p_all = np.asarray(columnwise(
-                    dp_transform, self.tiled, state["d"], slot.roots()))
+                with span("parents"):
+                    p_all = np.asarray(columnwise(
+                        dp_transform, self.tiled, state["d"], slot.roots()))
             for col, q in enumerate(slot.queries):
                 parents = None
                 if q.need_parents:
@@ -425,8 +451,9 @@ class Dispatcher:
         col_buckets = np.asarray(state["buckets"])
         p_all = None
         if need_dp:
-            p_all = np.asarray(columnwise(
-                sssp_parents, self.tiled, state["dist"], slot.roots()))
+            with span("parents"):
+                p_all = np.asarray(columnwise(
+                    sssp_parents, self.tiled, state["dist"], slot.roots()))
         for col, q in enumerate(slot.queries):
             parents = p_all[col] if q.need_parents else None
             self._finish(q, values=d[col], parents=parents,
@@ -436,8 +463,10 @@ class Dispatcher:
     # ------------------------------------------------- synchronous fallback
 
     def _dispatch_sync(self, slot: BatchSlot) -> None:
-        """Hostloop mode / boolean CC: run through the core front doors
-        (their loops are host control flow) and complete immediately."""
+        """Hostloop mode / boolean CC / betweenness: run through the core
+        front doors (their loops are host control flow) and complete
+        immediately. Their results carry no work log (none is asked for),
+        so they add nothing to the tile counters."""
         cfg, alg, sem = self.config, slot.key.algorithm, slot.key.semiring
         if alg == "cc":
             res = cc(self.tiled, semiring=sem, slimwork=self.slimwork,

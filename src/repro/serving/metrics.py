@@ -5,24 +5,30 @@ lives here: how full the device batches run (``batch_fill_ratio`` — the
 number the shape-bucketed batcher exists to maximize), whether the jit
 compile cache is actually being reused (``compile_cache_hits`` vs
 ``_misses`` — a miss per batch means the bucket widths are churning),
-queue pressure (``queue_depth``), end-to-end latency quantiles, and the
-amortization headline: engine sweeps per served query.
+queue pressure (``queue_depth``, ``queue_wait_s``), end-to-end latency
+quantiles, the amortization headline: engine sweeps per served query, and
+the engine's tile counts: how many tiles the SlimWork mask selected against
+how many the sweeps read.
 
-``ServingMetrics`` is deliberately dumb — plain ints and a latency list —
-but since the background-flush-thread PR it is **lock-protected**: the
+``ServingMetrics`` is deliberately dumb — plain numbers and a ring of the
+most recent latencies — but it is **lock-protected**: the background
 flush thread increments from its drain loop while caller threads submit
 and snapshot concurrently, so every mutation goes through ``inc()`` /
 ``record_latency()`` (one short critical section each) and ``snapshot()``
-copies the counters under the same lock. The invariant snapshots must
-preserve — and ``tests/test_serving_concurrent.py`` asserts — is the
-lifecycle reconciliation ``submitted == completed + timeouts + shed`` once
-the session is drained.
+copies the counters under the same lock. The
+invariant snapshots must preserve — and ``tests/test_serving_concurrent.py``
+asserts — is the lifecycle reconciliation ``submitted == completed +
+timeouts + shed`` once the session is drained.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
-from typing import List
+from typing import Deque, List
+
+#: latencies kept for the quantiles: the most recent this many queries
+LATENCY_WINDOW = 4096
 
 
 def _percentile(sorted_vals: List[float], q: float) -> float:
@@ -54,7 +60,17 @@ class ServingMetrics:
     * ``sweeps_total`` — engine fixpoint iterations executed across all
       batches (one sweep advances every column of its batch, which is the
       whole amortization argument).
-    * ``latencies_s`` — per-query submit-to-harvest wall times.
+    * ``tiles_active_total`` / ``tiles_read_total`` — summed over the
+      sweeps of handle-path batches: tiles the SlimWork mask selected (all
+      tiles without a mask), and tiles the sweep read (every tile on the
+      jnp backend, whose gather is unmasked; the selected ones on Pallas).
+    * ``queue_wait_s`` — seconds dispatched batch columns waited in the
+      queue, summed: dispatch instant less ``submitted_at`` for a column's
+      query (the mean over its queries where whole-graph queries share one
+      column), so its mean is over ``columns_real``.
+    * ``latencies_s`` — submit-to-harvest wall times of the most recent
+      ``LATENCY_WINDOW`` queries (a ring: a long-lived session holds and
+      sorts no more than that).
 
     Mutate through ``inc(counter=delta, ...)`` — direct attribute writes
     are not thread-safe against the flush thread.
@@ -69,11 +85,15 @@ class ServingMetrics:
     compile_cache_hits: int = 0
     compile_cache_misses: int = 0
     sweeps_total: int = 0
-    latencies_s: List[float] = dataclasses.field(default_factory=list)
+    tiles_active_total: int = 0
+    tiles_read_total: int = 0
+    queue_wait_s: float = 0.0
+    latencies_s: Deque[float] = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
     _lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False, compare=False)
 
-    def inc(self, **deltas: int) -> None:
+    def inc(self, **deltas: float) -> None:
         """Atomically add ``deltas`` to the named counters (one lock hold
         for the whole group, so multi-counter updates — e.g. a batch's
         dispatched/columns trio — land as one consistent event)."""
@@ -106,6 +126,8 @@ class ServingMetrics:
             "batch_fill_ratio": (c["columns_real"] / c["columns_total"]
                                  if c["columns_total"] else float("nan")),
             "sweeps_per_query": c["sweeps_total"] / served,
+            "queue_wait_ms_mean": (1e3 * c["queue_wait_s"] / c["columns_real"]
+                                   if c["columns_real"] else float("nan")),
             "latency_mean_ms": (1e3 * sum(lat) / len(lat)) if lat
                                else float("nan"),
             "latency_p50_ms": 1e3 * _percentile(lat, 0.50),

@@ -62,6 +62,7 @@ from ..core.sssp import _resolve_delta, _require_weighted
 from .batcher import Batcher, Query, QueueFull
 from .dispatch import Dispatcher, QueryResult
 from .metrics import ServingMetrics
+from .tracing import span
 
 GraphLike = Union[np.ndarray, CSRGraph, SlimSellTiled]
 
@@ -201,6 +202,20 @@ class GraphSession:
         ``on_full="shed"`` the overflowing query completes immediately as
         a typed ``status="shed"`` result instead.
         """
+        with span("submit", algorithm=algorithm) as sp:
+            handle = self._enqueue(
+                algorithm, root, semiring=semiring, delta=delta,
+                need_parents=need_parents, packed=packed, k=k,
+                damping=damping, tol=tol, deadline=deadline)
+            sp.set_metadata(qid=handle.qid)
+        return handle
+
+    def _enqueue(self, algorithm: str, root: Optional[int], *,
+                 semiring: Optional[str], delta: Optional[float],
+                 need_parents: bool, packed: bool, k: Optional[int],
+                 damping: Optional[float], tol: Optional[float],
+                 deadline: Optional[float]) -> QueryHandle:
+        """``submit`` less its span: validate, then queue or shed."""
         check_choice("algorithm", algorithm, ALGORITHMS)
         n = self.tiled.n
         if algorithm in ("cc", "pagerank", "betweenness"):
@@ -310,8 +325,9 @@ class GraphSession:
         queries past deadline complete as timeouts; launched batches beyond
         ``max_inflight`` are harvested (one step late). Thread-safe — the
         background flush thread calls exactly this."""
-        with self._flush_lock:
+        with self._flush_lock, span("flush") as sp:
             slots, expired = self.batcher.drain(self._clock())
+            sp.set_metadata(slots=len(slots))
             for q in expired:
                 self.dispatcher.expire(q)
             for slot in slots:

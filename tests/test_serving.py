@@ -13,7 +13,8 @@ from repro.core.formats import build_slimsell
 from repro.core.sssp import sssp
 from repro.graphs.generators import kronecker, with_random_weights
 from repro.serving import (Batcher, BucketKey, DeadlineExpired, GraphSession,
-                           Query, session)
+                           Query, ServingMetrics, session)
+from repro.serving.metrics import LATENCY_WINDOW
 
 
 @pytest.fixture(scope="module")
@@ -182,6 +183,23 @@ def test_compile_cache_hit_counting(wtiled):
     s.bfs_many([8, 9])               # width 2: new signature, miss
     st = s.stats()
     assert st["compile_cache_hits"] == 1 and st["compile_cache_misses"] == 2
+
+
+# ---------------------------------------------------------- latency window
+
+
+def test_latencies_keep_only_the_most_recent_window():
+    m = ServingMetrics()
+    total = LATENCY_WINDOW + 904
+    for i in range(total):
+        m.record_latency(i / 1000)
+    assert len(m.latencies_s) == LATENCY_WINDOW
+    assert m.latencies_s[0] == 904 / 1000
+    st = m.snapshot()
+    assert st["latency_mean_ms"] == pytest.approx(
+        (904 + total - 1) / 2)       # the mean of 904..4999 ms
+    assert st["latency_p99_ms"] == pytest.approx(
+        904 + round(0.99 * (LATENCY_WINDOW - 1)))
 
 
 # ----------------------------------------------------------- batcher units
