@@ -54,6 +54,7 @@ from . import direction as dm
 from . import engine as eng
 from . import packing
 from . import semiring as sm
+from . import spmv
 from .engine import DIRECTIONS, WORK_LOG, FixpointSpec  # noqa: F401 (re-export)
 from .options import EngineConfig, MODES, check_choice, resolve_config
 
@@ -245,40 +246,77 @@ def packed_bfs_spec(n: int) -> FixpointSpec:
 # ---------------------------------------------------------------- DP transform
 
 
-def dp_transform(tiled, d: Array, root) -> Array:
-    """p = DP(d): for each v pick a neighbor w with d[w] == d[v]-1 (paper §II-C).
+@functools.partial(jax.jit, static_argnames=("weighted", "rtol", "atol"))
+def dp_transform(tiled, D: Array, roots, *, weighted: bool = False,
+                 rtol: float = 1e-6, atol: float = 1e-6) -> Array:
+    """p = DP(d): for each v pick a neighbor w with d[w] == d[v]-1 (paper §II-C),
+    for every column of ``D`` in one sel-max SlimSell sweep.
 
-    One SlimSell sweep under the sel-max semiring; O(m+n) work, O(1) depth.
+    ``D`` is [n, B]: int32 BFS levels (-1 unreachable), or float32 distances
+    (+inf unreachable) when ``weighted``; ``roots`` is int32[B]. Returns
+    int32 parents [B, n], each root its own parent. A 1-D ``D`` [n] with a
+    scalar root is one column and returns [n].
+
+    Each slot (chunk row v, column u) gathers the row ``D[u, :]`` once and
+    scores it against the chunk row's own ``D[v, :]``:
+
+    * levels: ``u + 1`` where ``D[u] == D[v] - 1`` and v is reached and not
+      a root;
+    * ``weighted`` (``sssp.sssp_parents``): ``u + 1`` where the relaxation
+      is tight, ``|D[u] + w - D[v]| <= atol + rtol * |D[v]|``, plus ``n``
+      where ``w > 0``, so a positive-weight parent wins over a zero-weight
+      one.
+
+    The largest score over the slots, the SlimChunk tiles of a chunk and
+    the chunk rows of a vertex names the parent. Tiles are swept in groups
+    sized by ``spmv.gather_group``, as ``spmv.slimsell_spmm`` sweeps them,
+    so no [T, C, L, B] block is built whole. O(m+n) work per column, O(1)
+    depth. One program, cached on the layout's shapes, the width B and the
+    dtype of ``D``.
     """
-    pad = tiled.cols < 0
-    safe = jnp.where(pad, 0, tiled.cols)
-    d_nbr = jnp.take(d, safe, axis=0)                       # [T, C, L]
-    rv_tile = jnp.take(tiled.row_vertex, tiled.row_block, axis=0)  # [T, C]
-    rv_safe = jnp.where(rv_tile < 0, 0, rv_tile)
-    d_row = jnp.take(d, rv_safe, axis=0)[:, :, None]
-    ok = (~pad) & (d_row > 0) & (d_nbr == d_row - 1) & (d_nbr >= 0)
-    cand = jnp.where(ok, safe + 1, 0)
-    tile_red = cand.max(axis=-1)
-    y_blocks = jax.ops.segment_max(tile_red, tiled.row_block, num_segments=tiled.n_chunks)
+    single = D.ndim == 1
+    if single:
+        D = D[:, None]
+    roots = jnp.reshape(roots, (-1,)).astype(jnp.int32)
+    n, width = tiled.n, D.shape[1]
+    if weighted and tiled.wts is None:
+        raise ValueError("the weighted parent transform needs stored weights"
+                         " (a layout built from a weighted CSR)")
+    wts = tiled.wts if weighted else None
+
+    def best_of(tile):
+        cols, w, rb = tile                           # [C, L], [C, L], []
+        pad = (cols < 0)[..., None]
+        safe = jnp.where(cols < 0, 0, cols)
+        rv = tiled.row_vertex[rb]                    # [C]
+        d_row = jnp.take(D, jnp.where(rv < 0, 0, rv), axis=0)[:, None]
+        d_nbr = jnp.take(D, safe, axis=0)            # [C, L, B]
+        if not weighted:
+            ok = ~pad & (d_row > 0) & (d_nbr == d_row - 1) & (d_nbr >= 0)
+            best = jnp.where(ok, (safe + 1)[..., None], 0).max(axis=1)
+        else:
+            d_nbr = d_nbr + w[..., None]
+            tight = ~pad & jnp.isfinite(d_row) \
+                & (jnp.abs(d_nbr - d_row) <= atol + rtol * jnp.abs(d_row))
+            score = (safe + 1).astype(jnp.float32) \
+                + jnp.where(w > 0, float(n), 0.0)
+            best = jnp.where(tight, score[..., None], 0.0).max(axis=1)
+        return best.reshape(-1)                      # [C * B]
+
+    group = spmv.gather_group(tiled.cols.shape, width, D.dtype.itemsize)
+    tile_best = jax.lax.map(best_of, (tiled.cols, wts, tiled.row_block),
+                            batch_size=group)        # [T, C * B]
+    y_blocks = jax.ops.segment_max(tile_best, tiled.row_block,
+                                   num_segments=tiled.n_chunks)
     rv = tiled.row_vertex.reshape(-1)
-    ids = jnp.where(rv < 0, tiled.n, rv)
-    p1 = jax.ops.segment_max(y_blocks.reshape(-1), ids, num_segments=tiled.n + 1)[: tiled.n]
-    p = p1.astype(jnp.int32) - 1
-    return p.at[root].set(root)
-
-
-def columnwise(fn, tiled, D: Array, roots) -> Array:
-    """``fn(tiled, D[:, b], roots[b])`` for every column b of ``D`` [n, B],
-    stacked [B, n] — the parent transforms (``dp_transform``,
-    ``sssp.sssp_parents``) of a batch.
-
-    The columns run one after another on the device: a vmapped transform
-    would gather [T, C, L, B] at once, B times the cols array (19 GB for one
-    Graph500 scale-18 batch of 8 on a TPU, whose layout pads that minor
-    axis to 128 lanes).
-    """
-    return jax.lax.map(lambda col: fn(tiled, col[0], col[1]),
-                       (jnp.asarray(D).T, jnp.asarray(roots)))
+    ids = jnp.where(rv < 0, n, rv)
+    p1 = jax.ops.segment_max(y_blocks.reshape(-1, width), ids,
+                             num_segments=n + 1)[:n]
+    if weighted:
+        p1 = jnp.where(p1 > n, p1 - n, p1)  # strip the positive-weight bonus
+    p = (p1.astype(jnp.int32) - 1).T
+    p = p.at[jnp.arange(width), roots].set(roots)
+    return p[0] if single else p
 
 
 # ----------------------------------------------------------------- public API

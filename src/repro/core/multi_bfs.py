@@ -47,8 +47,8 @@ from . import engine as eng
 from . import packing
 from . import semiring as sm
 from .bfs import (_check_bfs_options, _check_packed, _frontier_payload,
-                  _host_direction_bits, _ids1, _not_final, columnwise,
-                  dp_transform, semiring_update)
+                  _host_direction_bits, _ids1, _not_final, dp_transform,
+                  semiring_update)
 from .engine import DIRECTIONS, WORK_LOG, FixpointSpec  # noqa: F401
 from .options import EngineConfig, resolve_config
 
@@ -272,8 +272,7 @@ def multi_source_bfs(tiled, roots: Sequence[int],
             if semiring == "selmax":
                 p = np.asarray(state["p"].astype(jnp.int32) - 1).T
             else:
-                p = np.asarray(columnwise(dp_transform, tiled, state["d"],
-                                          batch_p))
+                p = np.asarray(dp_transform(tiled, state["d"], batch_p))
             p_out[start:start + batch.size] = p[: batch.size]
             for b, r in enumerate(batch):
                 p_out[start + b, int(r)] = int(r)

@@ -63,7 +63,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import engine as eng
-from .bfs import columnwise
 from .engine import FixpointSpec
 from .multi_bfs import _iter_batches
 from .options import EngineConfig, MODES, check_choice, resolve_config
@@ -247,7 +246,7 @@ def multi_source_sssp(tiled, roots: Sequence[int], *,
     Returns per-root float32 distances (+inf unreachable), per-root
     sweep/bucket counts that match the per-root ``sssp`` engine exactly,
     and, when requested, shortest-path-tree parents via the weighted DP
-    sweep (one ``sssp_parents`` vmap over the batch).
+    sweep (``sssp_parents``, one sweep per batch).
     """
     cfg = resolve_config("multi_source_sssp", config, mode=mode,
                          backend=backend)
@@ -296,8 +295,7 @@ def multi_source_sssp(tiled, roots: Sequence[int], *,
         buckets[start:start + batch.size] = \
             np.asarray(state["buckets"])[: batch.size]
         if need_parents:
-            p = np.asarray(columnwise(sssp_parents, tiled, state["dist"],
-                                      batch_p))
+            p = np.asarray(sssp_parents(tiled, state["dist"], batch_p))
             p_out[start:start + batch.size] = p[: batch.size]
         iters.append(res.iterations)
         if log_work:

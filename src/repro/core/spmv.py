@@ -64,8 +64,23 @@ def resolve_backend(backend: Optional[str]) -> str:
     return b
 
 
-#: bytes of frontier rows the jnp SpMM gathers at once (see slimsell_spmm)
+#: bytes of frontier rows a jnp tile-grouped gather takes at once (see
+#: gather_group)
 SPMM_GATHER_BYTES = 256 * 2**20
+
+
+def gather_group(cols_shape, width: int, itemsize: int) -> int:
+    """Tiles per group of a tile-grouped gather of ``width``-wide rows.
+
+    The gathered [tiles, C, L, width] block is ``width`` times the size of
+    cols, so tiles are swept in groups whose gather stays near
+    SPMM_GATHER_BYTES (a whole-graph gather of a Graph500 scale-22 batch of
+    8 is 19 GB). A TPU lays the gather out [slots, width] with the width
+    padded to 128 lanes, so the group is sized for the padded width.
+    """
+    _, C, L = cols_shape
+    lanes = -(-width // 128) * 128
+    return max(1, SPMM_GATHER_BYTES // (C * L * lanes * max(itemsize, 4)))
 
 
 def tile_contributions(sr: Semiring, cols: Array, x: Array,
@@ -269,15 +284,7 @@ def slimsell_spmm(sr: Semiring, tiled, X: Array, *,
             return packing.or_reduce(contrib, (1,))
         return contrib.sum(axis=1)                # [C, d]
 
-    # the gathered [tiles, C, L, d] block is d times the size of cols, so
-    # tiles are swept in groups whose gather stays near SPMM_GATHER_BYTES
-    # (a whole-graph gather of a Graph500 scale-22 batch of 8 is 19 GB).
-    # A TPU lays the gather out [slots, d] with d padded to 128 lanes, so
-    # the group is sized for the padded width.
-    _, C, L = tiled.cols.shape
-    lanes = -(-X.shape[-1] // 128) * 128
-    group = max(1, SPMM_GATHER_BYTES // (C * L * lanes
-                                         * max(X.dtype.itemsize, 4)))
+    group = gather_group(tiled.cols.shape, X.shape[-1], X.dtype.itemsize)
     tile_red = jax.lax.map(tile_red_of, (tiled.cols, weights, rv_tiles),
                            batch_size=group)      # [T, C, d]
     return _combine_and_scatter(sr, tiled, tile_red, tile_mask)

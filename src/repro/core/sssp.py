@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import engine as eng
+from .bfs import dp_transform
 from .engine import FixpointSpec
 from .options import EngineConfig, MODES, check_choice, resolve_config
 
@@ -244,10 +245,14 @@ SSSP_SPEC = FixpointSpec(
 # -------------------------------------------------------- parents (weighted DP)
 
 
-def sssp_parents(tiled, dist: Array, root, *, rtol: float = 1e-6,
+def sssp_parents(tiled, dist: Array, roots, *, rtol: float = 1e-6,
                  atol: float = 1e-6) -> Array:
     """Weighted DP transform: for each v pick a neighbor u whose relaxation is
     tight, ``dist[u] + w(v, u) == dist[v]`` (one sel-max SlimSell sweep).
+
+    One sweep for a whole batch: distances ``dist`` [n, B] and ``roots``
+    [B] give parents [B, n]; one column [n] with a scalar root gives [n]
+    (``bfs.dp_transform`` with ``weighted=True``).
 
     Positive-weight parents are preferred over zero-weight ones (a ``+ n``
     score bonus), so parent chains strictly decrease ``dist`` whenever any
@@ -262,28 +267,8 @@ def sssp_parents(tiled, dist: Array, root, *, rtol: float = 1e-6,
         raise ValueError("sssp_parents carries (vertex id + n) scores in "
                          "float32 (exact up to 2^24), so n is capped at "
                          f"2^23; got n={n}")
-    pad = tiled.cols < 0
-    safe = jnp.where(pad, 0, tiled.cols)
-    d_nbr = jnp.take(dist, safe, axis=0) + tiled.wts            # [T, C, L]
-    rv_tile = jnp.take(tiled.row_vertex, tiled.row_block, axis=0)  # [T, C]
-    rv_safe = jnp.where(rv_tile < 0, 0, rv_tile)
-    d_row = jnp.take(dist, rv_safe, axis=0)[:, :, None]
-    tight = (~pad) & jnp.isfinite(d_row) \
-        & (jnp.abs(d_nbr - d_row) <= atol + rtol * jnp.abs(d_row))
-    score = jnp.where(tight,
-                      (safe + 1).astype(jnp.float32)
-                      + jnp.where(tiled.wts > 0, float(n), 0.0),
-                      0.0)
-    tile_red = score.max(axis=-1)
-    y_blocks = jax.ops.segment_max(tile_red, tiled.row_block,
-                                   num_segments=tiled.n_chunks)
-    rv = tiled.row_vertex.reshape(-1)
-    ids = jnp.where(rv < 0, n, rv)
-    p1 = jax.ops.segment_max(y_blocks.reshape(-1), ids,
-                             num_segments=n + 1)[:n]
-    p1 = jnp.where(p1 > n, p1 - n, p1)  # strip the positive-weight bonus
-    p = p1.astype(jnp.int32) - 1
-    return p.at[root].set(root)
+    return dp_transform(tiled, dist, roots, weighted=True, rtol=rtol,
+                        atol=atol)
 
 
 # ------------------------------------------------------------- host oracle

@@ -55,7 +55,7 @@ import numpy as np
 
 from ..core import engine as eng
 from ..core.betweenness import betweenness
-from ..core.bfs import columnwise, dp_transform
+from ..core.bfs import dp_transform
 from ..core.cc import CC_SPEC, cc
 from ..core.formats import layout_signature
 from ..core.khop import khop_many
@@ -431,12 +431,11 @@ class Dispatcher:
             if need_dp and sem == "selmax":
                 p_all = np.asarray(state["p"].astype(jnp.int32) - 1).T
             elif need_dp:
-                # one on-device loop of DP sweeps serves every column
-                # (mirrors multi_source_bfs — per-column eager calls would
-                # dominate the harvest)
+                # one batched DP sweep serves every column (as in
+                # multi_source_bfs)
                 with span("parents"):
-                    p_all = np.asarray(columnwise(
-                        dp_transform, self.tiled, state["d"], slot.roots()))
+                    p_all = np.asarray(dp_transform(
+                        self.tiled, state["d"], slot.roots()))
             for col, q in enumerate(slot.queries):
                 parents = None
                 if q.need_parents:
@@ -452,8 +451,8 @@ class Dispatcher:
         p_all = None
         if need_dp:
             with span("parents"):
-                p_all = np.asarray(columnwise(
-                    sssp_parents, self.tiled, state["dist"], slot.roots()))
+                p_all = np.asarray(sssp_parents(
+                    self.tiled, state["dist"], slot.roots()))
         for col, q in enumerate(slot.queries):
             parents = p_all[col] if q.need_parents else None
             self._finish(q, values=d[col], parents=parents,

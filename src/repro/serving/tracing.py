@@ -25,7 +25,7 @@ SPANS = (
     #                        width, n_real, qids]
     "repro.harvest",       # Dispatcher: one batch's results [batch, qids]
     "repro.harvest.wait",  # inside harvest: the host waits on the fixpoint
-    "repro.parents",       # inside harvest: the columnwise parent transform
+    "repro.parents",       # inside harvest: the batched parent transform
 )
 
 

@@ -17,11 +17,12 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import engine as eng
-from repro.core.bfs import bfs_spec, columnwise, dp_transform
+from repro.core.bfs import bfs_spec, dp_transform
 from repro.core.cc import CC_SPEC
 from repro.core.formats import SlimSellTiled
 from repro.core.multi_bfs import multi_bfs_spec
 from repro.core.multi_sssp import MULTI_SSSP_SPEC
+from repro.core.sssp import sssp_parents
 from repro.kernels import sweep
 from repro.kernels.slimsell_packed import (slimsell_spmm_packed_pallas,
                                            slimsell_spmv_packed_pallas)
@@ -164,12 +165,21 @@ def test_jnp_engine_handle_compiles_for_v5e(one_chip, name):
                                    on_chip(state)).compile())
 
 
-def test_parent_transform_compiles_for_v5e(one_chip):
-    """Graph500 validation's parents: the DP transform of a batch of 8
-    columns at scale 22 fits one chip's HBM."""
-    tiled = _scale22_layout(one_chip, weighted=False)
-    _fits_hbm(jax.jit(lambda t, d, r: columnwise(dp_transform, t, d, r)).lower(
-        tiled, _shape(one_chip, (N, B), jnp.int32),
+PARENT_TRANSFORMS = {
+    # name: (transform, dtype of the distances)
+    "bfs": (dp_transform, jnp.int32),
+    "sssp": (sssp_parents, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TRANSFORMS))
+def test_parent_transform_compiles_for_v5e(one_chip, name):
+    """Graph500 validation's parents: the batched transform of 8 columns
+    at scale 22 (levels, or weighted distances) fits one chip's HBM."""
+    fn, dtype = PARENT_TRANSFORMS[name]
+    tiled = _scale22_layout(one_chip, weighted=True)
+    _fits_hbm(jax.jit(fn).lower(
+        tiled, _shape(one_chip, (N, B), dtype),
         _shape(one_chip, (B,), jnp.int32)).compile())
 
 
