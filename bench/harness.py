@@ -13,6 +13,14 @@ drivers:
   instants through ``GraphSession.submit``, each timed from its scheduled
   instant to its result.
 
+The program's front door builds the layout: set-up makes the
+configuration's host CSR (``gen.from_config``) and hands it to
+``GraphSession(csr, **layout, **session)``, where ``layout`` is every key of
+the configuration's ``layout`` but ``sigma`` (``C``, ``L`` and any key the
+session takes, such as a mesh) and ``session`` its ``session``. The session
+builds every layout with sigma = n, so a configuration's ``sigma`` has to be
+``"n"``; another is refused.
+
 A run records what it saw in a ``Run``; the metric readers compute every
 reported number from it.
 """
@@ -131,12 +139,13 @@ def device_info(chips: int, require_tpu: bool = True) -> dict:
 
 def sweep_floor_ms(layout: dict, kind: str) -> Optional[float]:
     """Least time one full sweep could take: reading the layout's int32
-    column ids once at the chip's peak HBM bandwidth."""
+    column ids once at the chip's peak HBM bandwidth, each device its
+    share of them."""
     try:
         bw = peaks_for(kind)["hbm_bytes_per_s"]
     except KeyError:
         return None
-    return 1e3 * 4 * layout["slots"] / bw
+    return 1e3 * 4 * layout["slots"] / layout["devices"] / bw
 
 
 def memory_peak_bytes() -> int:
@@ -171,26 +180,40 @@ def log(msg: str) -> None:
 # ------------------------------------------------------------------ set-up
 
 
+def layout_kwargs(config: dict) -> dict:
+    """The configuration's ``layout`` as keyword arguments of the program's
+    front door: every key but ``sigma``, which has to be ``"n"``."""
+    lay = dict(config["layout"])
+    sigma = lay.pop("sigma", "n")
+    if sigma != "n":
+        raise ValueError(f"layout sigma must be 'n', got {sigma!r}: "
+                         f"GraphSession builds every layout with sigma = n")
+    return lay
+
+
 def build(run: Run):
-    """The configuration's data on the device, then the program's layout."""
-    from repro.core.formats import CSRGraph, build_slimsell
+    """The configuration's data on the device, then the session that the
+    program's front door builds from its CSR, with the layout resident."""
+    import jax
+    from repro.core.formats import CSRGraph
+    from repro.serving import GraphSession
+    layout = layout_kwargs(run.config)
     t = time.monotonic()
     g = gen.from_config(run.config)
     run.setup["gen_s"] = time.monotonic() - t
-    lay = run.config["layout"]
     csr = CSRGraph(n=g.n, m_undirected=g.nnz // 2, indptr=g.indptr,
                    indices=g.indices, weights=g.weights)
     t = time.monotonic()
-    host = build_slimsell(csr, C=lay["C"], L=lay["L"], sigma=g.n)
-    tiled = host.to_jax()
-    tiled.cols.block_until_ready()
-    if tiled.wts is not None:
-        tiled.wts.block_until_ready()
+    sess = GraphSession(csr, **layout, **run.config["session"])
+    jax.block_until_ready(sess.tiled)
     run.setup["layout_build_s"] = time.monotonic() - t
-    run.layout = {"n": g.n, "nnz": g.nnz, "slots": int(host.cols.size),
-                  "tiles": int(host.n_tiles),
+    cols = sess.tiled.cols
+    C, L = cols.shape[-2:]
+    run.layout = {"n": g.n, "nnz": g.nnz, "slots": int(cols.size),
+                  "tiles": int(cols.size // (C * L)),
+                  "devices": len(cols.sharding.device_set),
                   "isolated": int((g.deg == 0).sum())}
-    return g, tiled
+    return g, sess
 
 
 #: the program's serving counters (``ServingMetrics``) a run reads
@@ -458,9 +481,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     run.setup["t0"] = t0
     run.setup["jax_init_s"] = time.monotonic() - t0
 
-    from repro.serving import GraphSession
-    g, tiled = build(run)
-    sess = GraphSession(tiled, **config["session"])
+    g, sess = build(run)
     drive = drive_batches if run.kind == "batches" else drive_open_loop
     if trace:
         kept = _trace_window(run, drive, run, g, sess, counter)
@@ -468,7 +489,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         kept = drive(run, g, sess, counter)
     peak = memory_peak_bytes()
     sess.close()
-    del sess, tiled
+    del sess
     run.setup.pop("t0")
 
     w = run.window

@@ -60,7 +60,6 @@ def main(argv=None) -> int:
     cell, config, traffic = harness.find_cell(spec, args.workload)
     import jax
     from repro.compile_cache import use_compile_cache
-    from repro.serving import GraphSession
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     harness.device_info(int(cell["chips"]))
@@ -68,8 +67,7 @@ def main(argv=None) -> int:
     base = harness.Run(workload=args.workload, kind=traffic["kind"],
                        seed=args.seed, seconds=args.seconds, cell=cell,
                        config=config, traffic=traffic)
-    g, tiled = harness.build(base, args.seed)
-    sess = GraphSession(tiled, **config["session"])
+    g, sess = harness.build(base)
     harness.warm_open_loop(sess, traffic, g,
                            schedule.rng_for(args.seed, "warm"))
     harness.log(f"[knee] set-up {time.monotonic() - t0:.1f} s")

@@ -1,5 +1,5 @@
-"""Host seconds of the program's ``build_slimsell`` and ``to_jax`` (until
-the layout is resident) inside set-up."""
+"""Host seconds of the program's front door, ``GraphSession`` built from
+the host CSR, until its layout is resident: inside set-up."""
 
 
 def read(run):

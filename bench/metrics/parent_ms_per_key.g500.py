@@ -1,6 +1,6 @@
-"""Device milliseconds of the parent transform's programs (the columnwise
-``dp_transform`` or ``sssp_parents`` of every batch) in the traced window,
-per key completed in it."""
+"""Device milliseconds of the parent transform's programs (the batched
+``dp_transform`` of every batch, which ``sssp_parents`` runs too) in the
+traced window, per key completed in it."""
 from bench.trace import parent_transform_s
 
 

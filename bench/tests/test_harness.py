@@ -98,6 +98,76 @@ def test_every_batch_is_checked_on_the_control_s_sample(tiny_root,
     assert answered == checked[:take]
 
 
+# ---------------------------------------------------------- front door
+
+
+def _config(root, name="g500-kron", **layout):
+    config = json.loads((root / "bench" / "configs" / f"{name}.json")
+                        .read_text())
+    config["layout"].update(layout)
+    return config
+
+
+def _build(config):
+    run_ = harness.Run(workload="g500-bfs", kind="batches", seed=1,
+                       seconds=SECONDS, cell={}, config=config, traffic={})
+    return run_, harness.build(run_)
+
+
+@pytest.mark.parametrize("weighted", (True, False))
+def test_front_door_layout_equals_the_harness_s_own_build(tiny_root,
+                                                          weighted):
+    """The session's layout, built from the CSR, is the one the harness
+    built itself with ``build_slimsell(csr, C, L, sigma=n).to_jax()``."""
+    from repro.core.formats import CSRGraph, build_slimsell
+    config = _config(tiny_root)
+    if not weighted:
+        for key in ("weight_low", "weight_high", "weight_seed"):
+            del config["graph"][key]
+    run_, (g, sess) = _build(config)
+    try:
+        lay = config["layout"]
+        csr = CSRGraph(n=g.n, m_undirected=g.nnz // 2, indptr=g.indptr,
+                       indices=g.indices, weights=g.weights)
+        host = build_slimsell(csr, C=lay["C"], L=lay["L"], sigma=g.n)
+        want = host.to_jax()
+        for field in ("cols", "row_block", "wts"):
+            got, ref = getattr(sess.tiled, field), getattr(want, field)
+            assert (got is None) == (ref is None)
+            if ref is not None:
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(ref))
+        assert (want.wts is None) == (not weighted)
+        assert run_.layout["slots"] == host.cols.size
+        assert run_.layout["tiles"] == host.n_tiles
+        assert run_.layout["devices"] == 1
+    finally:
+        sess.close()
+
+
+def test_layout_keys_reach_the_session(tiny_root, monkeypatch):
+    """Every ``layout`` key but ``sigma`` is a keyword of the session, so a
+    configuration can ask the program for a mesh."""
+    import repro.serving
+    from repro.core.formats import build_slimsell
+    seen = {}
+
+    class Session:
+        def __init__(self, graph, **kwargs):
+            seen.update(kwargs)
+            self.tiled = build_slimsell(graph, C=kwargs["C"], L=kwargs["L"],
+                                        sigma=graph.n).to_jax()
+    monkeypatch.setattr(repro.serving, "GraphSession", Session)
+    config = _config(tiny_root, mesh=[2, 2])
+    _build(config)
+    assert seen == {"C": 8, "L": 128, "mesh": [2, 2], "max_batch": 16}
+
+
+def test_a_sigma_other_than_n_is_refused(tiny_root):
+    with pytest.raises(ValueError, match="sigma"):
+        _build(_config(tiny_root, sigma=1))
+
+
 # ------------------------------------------------------------------ faults
 
 
@@ -105,7 +175,8 @@ def _state_unchanged(monkeypatch):
     from repro.core import engine
 
     def run(self, tiled, ctx, state):
-        return state, jnp.asarray(0, jnp.int32)
+        zero = jnp.asarray(0, jnp.int32)
+        return state, zero, (zero, zero)
     monkeypatch.setattr(engine.FixpointHandle, "run", run)
 
 
@@ -117,14 +188,14 @@ def _half_batch_left_out(monkeypatch):
 
     def run(self, tiled, ctx, state):
         init = {k: jnp.array(v) for k, v in state.items()}
-        out, iters = real(self, tiled, ctx, state)
+        out, iters, tiles = real(self, tiled, ctx, state)
         width = self.batch_width
         if width:
             keep = width // 2
             out = {k: (v.at[:, keep:].set(init[k][:, keep:])
                        if v.ndim == 2 and v.shape[1] == width else v)
                    for k, v in out.items()}
-        return out, iters
+        return out, iters, tiles
     monkeypatch.setattr(engine.FixpointHandle, "run", run)
 
 

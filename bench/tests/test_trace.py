@@ -1,7 +1,7 @@
 """The trace reduction on synthetic events."""
 import pytest
 
-from bench import trace
+from bench import harness, trace
 
 
 def test_union_and_gaps():
@@ -34,13 +34,46 @@ def test_reduce_events_busy_ops_and_gaps():
 def test_programs_split_busy_time_and_parent_transform():
     ops = {"/device:TPU:0": [("jit_impl/%while.1 while", 0, 300),
                              ("jit_impl/%fusion.2 fusion", 10, 200),
-                             ("jit_scan/%fusion.9 fusion", 400, 900),
+                             ("jit_dp_transform/%fusion.9 fusion", 400, 900),
                              ("loose", 950, 1000)]}
     out = trace.reduce_events(ops, {"bench.window": [(0, 1000)]})
     assert out["programs"] == pytest.approx(
-        {"jit_impl": 300e-9, "jit_scan": 500e-9, "": 50e-9})
+        {"jit_impl": 300e-9, "jit_dp_transform": 500e-9, "": 50e-9})
     assert trace.parent_transform_s(out["programs"]) == pytest.approx(500e-9)
     assert trace.parent_transform_s({"jit_impl": 1.0}) == 0.0
+    assert trace.parent_transform_s({"jit_scan": 1.0}) == 0.0
+
+
+def _batches_run(device_trace, completed, sweeps):
+    return harness.Run(workload="g500-bfs", kind="batches", seed=1,
+                       seconds=1.0, cell={}, config={}, traffic={},
+                       window={"completed": completed},
+                       counters={"sweeps_total": sweeps},
+                       device_trace=device_trace)
+
+
+def test_transform_is_read_per_key_and_left_out_of_the_sweep():
+    """The batched parent transform's program counts for
+    ``parent_ms_per_key.g500`` and not for ``dev_ms_per_sweep.g500``."""
+    ops = {"/device:TPU:0": [
+        ("jit_fixpoint_multi_bfs_tropical/%fusion.39 fusion", 0, 6e8),
+        ("jit_dp_transform/%fusion.36 fusion", 6e8, 8e8),
+        ("jit_fixpoint_multi_bfs_tropical/%fusion.39 fusion", 8e8, 14e8)]}
+    t = trace.reduce_events(ops, {"bench.window": [(0, 15e8)]})
+    run = _batches_run(t, completed=4, sweeps=3)
+    parent = harness.load_reader("parent_ms_per_key.g500")(run)
+    sweep = harness.load_reader("dev_ms_per_sweep.g500")(run)
+    assert parent == pytest.approx(200.0 / 4)
+    assert sweep == pytest.approx(1200.0 / 3)
+
+
+def test_transform_reader_is_silent_without_the_transform():
+    ops = {"/device:TPU:0": [("jit_fixpoint_multi_sssp/%f fusion", 0, 9e8)]}
+    t = trace.reduce_events(ops, {"bench.window": [(0, 1e9)]})
+    run = _batches_run(t, completed=16, sweeps=3)
+    assert harness.load_reader("parent_ms_per_key.g500")(run) is None
+    assert harness.load_reader("dev_ms_per_sweep.g500")(run) == \
+        pytest.approx(300.0)
 
 
 def test_busy_is_averaged_over_devices():

@@ -34,10 +34,10 @@ DEVICE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 TOP = 10
-#: the XLA programs that hold the parent transform: the program's
-#: dispatcher runs ``core/bfs.py:columnwise`` as an eager ``lax.map``,
-#: which JAX compiles as ``jit_scan``
-PARENT_PROGRAMS = ("jit_scan",)
+#: the XLA programs that hold the parent transform: harvest runs
+#: ``core/bfs.py:dp_transform`` (BFS, and SSSP through ``sssp_parents``)
+#: on a batch's whole distance matrix, one jitted program
+PARENT_PROGRAMS = ("jit_dp_transform",)
 _OPCODE = re.compile(r" ([a-z][a-z0-9_\-]*)\(")
 
 Interval = Tuple[float, float]
